@@ -14,6 +14,7 @@ import numpy as np
 from benchmarks.common import emit, time_us
 from repro.core.adaptivfloat import af_encode
 from repro.kernels import ref
+from repro.kernels.dispatch import interpret_mode
 from repro.kernels.adaptivfloat_k import af_matmul, quantize
 from repro.kernels.block_sparse import block_sparse_matmul
 from repro.kernels.layernorm import layernorm
@@ -26,18 +27,19 @@ def _r(shape, seed=0, scale=1.0):
 
 
 def main() -> None:
+    interp = interpret_mode()
     # layernorm
     x = _r((4096, 768), 0, 3.0)
     g, b = _r((768,), 1), _r((768,), 2)
     us = time_us(jax.jit(lambda x: ref.layernorm(x, g, b)), x)
-    err = float(jnp.abs(layernorm(x[:256], g, b) - ref.layernorm(x[:256], g, b)).max())
+    err = float(jnp.abs(layernorm(x[:256], g, b, interpret=interp) - ref.layernorm(x[:256], g, b)).max())
     emit("kernel_layernorm_4096x768", us, f"ref_xla_cpu;kernel_err={err:.1e}")
 
     # softmax+entropy fused
     lg = _r((2048, 128), 3, 5.0)
     mask = jnp.ones_like(lg)
     us = time_us(jax.jit(lambda l: ref.softmax_entropy(l, mask)), lg)
-    p1, h1 = softmax_entropy(lg[:256], mask[:256])
+    p1, h1 = softmax_entropy(lg[:256], mask[:256], interpret=interp)
     p2, h2 = ref.softmax_entropy(lg[:256], mask[:256])
     emit(
         "kernel_softmax_entropy_2048x128", us,
@@ -48,14 +50,15 @@ def main() -> None:
     # AF quantize
     w = _r((1024, 1024), 4, 2.0)
     us = time_us(jax.jit(lambda w: ref.adaptivfloat_quantize(w)), w)
-    err = float(jnp.abs(quantize(w[:128]) - ref.adaptivfloat_quantize(w[:128])).max())
+    err = float(jnp.abs(quantize(w[:128], interpret=interp) - ref.adaptivfloat_quantize(w[:128])).max())
     emit("kernel_af_quantize_1024x1024", us, f"ref_xla_cpu;kernel_err={err:.1e}")
 
     # AF8 matmul: halves weight HBM traffic
     codes, e_min = af_encode(w)
     x2 = _r((256, 1024), 5)
     us = time_us(jax.jit(lambda x, c: ref.af_matmul(x, c, e_min)), x2, codes)
-    got = af_matmul(x2[:64], codes, e_min, bm=64, bk=128, bn=128)
+    got = af_matmul(x2[:64], codes, e_min, bm=64, bk=128, bn=128,
+                    interpret=interp)
     want = ref.af_matmul(x2[:64], codes, e_min)
     emit(
         "kernel_af_matmul_256x1024x1024", us,

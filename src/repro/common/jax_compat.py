@@ -1,61 +1,32 @@
-"""JAX version compatibility shims.
+"""The JAX mesh and ``shard_map`` spellings the repo uses (JAX 0.9 API).
 
-The repo targets the modern public API (``jax.shard_map``,
-``jax.make_mesh(..., axis_types=...)``, ``jax.sharding.AxisType``) but must
-also run on jax 0.4.x, where ``shard_map`` still lives under
-``jax.experimental`` and meshes have neither the ``axis_types`` kwarg nor the
-``AxisType`` enum (all axes behave as Auto).  Import from here instead of
-feature-detecting at every call site.
+Meshes are built with every axis ``AxisType.Auto``: sharding-in-types would
+otherwise make the default Explicit, and the serving and training code
+relies on the compiler propagating shardings.
 """
 from __future__ import annotations
 
-import inspect
 from typing import Sequence
 
 import jax
 
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # jax < 0.6: experimental namespace
-    from jax.experimental.shard_map import shard_map  # type: ignore[no-redef]
-
-HAS_AXIS_TYPES = hasattr(jax.sharding, "AxisType")
-
-# ``shard_map`` validates that every primitive in the body has a replication
-# rule unless told not to; ``pallas_call`` has none, so the serving stack's
-# Pallas-eligible fused steps MUST disable the check.  The kwarg was renamed
-# ``check_rep`` -> ``check_vma`` across jax versions — detect once here.
-_SM_PARAMS = frozenset(inspect.signature(shard_map).parameters)
-_NOREP_KW = (
-    {"check_vma": False} if "check_vma" in _SM_PARAMS
-    else {"check_rep": False} if "check_rep" in _SM_PARAMS
-    else {}
-)
-
 
 def shard_map_norep(f, mesh, in_specs, out_specs):
-    """``shard_map`` with replication checking off, on any supported jax.
+    """``jax.shard_map`` with the varying-manual-axes check off.
 
     Required whenever the mapped body may dispatch a ``pallas_call`` (no
     replication rule exists for it) — i.e. for every serving fused step,
     since Pallas eligibility is a static engine flag, not a trace property.
     """
-    return shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **_NOREP_KW
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )
 
 
 def make_auto_mesh(shape: Sequence[int], axis_names: Sequence[str]) -> "jax.sharding.Mesh":
-    """``jax.make_mesh`` with every axis Auto, on any supported jax version.
-
-    Newer jax wants explicit ``axis_types`` (sharding-in-types makes the
-    default Explicit on some versions); older jax rejects the kwarg and is
-    Auto-only anyway.
-    """
-    if HAS_AXIS_TYPES:
-        return jax.make_mesh(
-            tuple(shape),
-            tuple(axis_names),
-            axis_types=(jax.sharding.AxisType.Auto,) * len(tuple(axis_names)),
-        )
-    return jax.make_mesh(tuple(shape), tuple(axis_names))
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    names = tuple(axis_names)
+    return jax.make_mesh(
+        tuple(shape), names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(names),
+    )

@@ -164,11 +164,15 @@ def af_decode_static(codes: jnp.ndarray, e_min: int, fmt: AFFormat = AFFormat(),
     return af_decode(codes, jnp.asarray(e_min, jnp.int32), fmt, dtype)
 
 
-def fake_quant(x: jnp.ndarray, fmt: AFFormat, enabled: bool = True) -> jnp.ndarray:
-    """Straight-through fake-quant for activations (QAT / eval emulation)."""
+def fake_quant(
+    x: jnp.ndarray, fmt: AFFormat, enabled: bool = True,
+    amax: Optional[jnp.ndarray] = None,
+) -> jnp.ndarray:
+    """Straight-through fake-quant for activations (QAT / eval emulation);
+    ``amax`` as in ``af_quantize``."""
     if not enabled:
         return x
-    q = af_quantize(x, fmt)
+    q = af_quantize(x, fmt, amax=amax)
     # straight-through estimator: identity gradient
     return x + jax.lax.stop_gradient(q - x)
 

@@ -11,6 +11,7 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -40,7 +41,9 @@ def _quant_body(x, e_min, fmt: AFFormat):
 
 def _quantize_kernel(x_ref, emin_ref, o_ref, *, fmt: AFFormat):
     x = x_ref[...].astype(jnp.float32)
-    e_min = emin_ref[0]
+    # the bias is a [1, 1] block: a 1-D one becomes an illegal (8, 1) array
+    # with a (1, 1) block once the fused step vmaps this call over lanes
+    e_min = emin_ref[...]
     o_ref[...] = _quant_body(x, e_min, fmt).astype(o_ref.dtype)
 
 
@@ -48,13 +51,16 @@ def quantize(
     x: jnp.ndarray,           # [rows, d]
     *,
     fmt: AFFormat = AFFormat(),
+    amax: Optional[jnp.ndarray] = None,
     block_rows: int = 256,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
-    """Quantize-dequantize to the AdaptivFloat grid; per-tensor bias."""
+    """Quantize-dequantize to the AdaptivFloat grid; per-tensor bias from
+    ``amax`` (default: the tensor's own max-abs)."""
     rows, d = x.shape
-    amax = jnp.max(jnp.abs(x.astype(jnp.float32)))
-    amax = jnp.maximum(amax, 1e-30)
+    if amax is None:
+        amax = jnp.max(jnp.abs(x.astype(jnp.float32)))
+    amax = jnp.maximum(amax.astype(jnp.float32), 1e-30)
     e_min = jnp.clip(
         jnp.floor(jnp.log2(amax)) - (fmt.n_levels_exp - 1), -120.0, 120.0
     ).astype(jnp.float32)
@@ -70,12 +76,12 @@ def quantize(
         grid=(n_blocks,),
         in_specs=[
             pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
-            pl.BlockSpec((1,), lambda i: (0,)),
+            pl.BlockSpec((1, 1), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         interpret=interpret,
-    )(x, e_min[None])
+    )(x, e_min.reshape(1, 1))
     return out[:rows]
 
 
@@ -121,7 +127,7 @@ def af_matmul(
     bm: int = 128,
     bk: int = 128,
     bn: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     M, K = x.shape
     K2, N = w_codes.shape

@@ -66,7 +66,7 @@ def block_sparse_matmul(
     bm: int = 128,
     bk: int = 128,
     bn: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     M, K = x.shape
     K2, N = w.shape

@@ -12,9 +12,10 @@ Rules the dispatchers obey (the engine's compile guarantees depend on them):
     the ref path (zero-NEW-traces per request either way);
   * everything traced stays traced: per-lane `kv_len` rides into the span
     kernel via scalar prefetch, spans/shapes/block masks are static;
-  * on CPU (no TPU backend) kernels run in interpret mode — the same
-    `pallas_call`s execute their bodies in Python, so CI exercises the
-    exact kernel code paths that Mosaic compiles on TPU.
+  * `interpret_mode()` decides how every kernel runs: compiled by Mosaic
+    on the TPU, interpreted on the CPU backend (the same `pallas_call`s
+    execute their bodies in Python, so CPU tests exercise the exact kernel
+    code that Mosaic compiles), and an error on any other backend.
 
 Eligibility notes:
   * soft (trained) spans taper probabilities over a ramp; the hard-window
@@ -30,7 +31,7 @@ Eligibility notes:
   * every dispatcher stays eligible INSIDE `shard_map` (the multi-device
     serving path): `pallas_call` has no replication rule, so the sharded
     fused-step wrappers must go through `jax_compat.shard_map_norep`
-    (check_rep/check_vma off).  Nothing here may introduce a cross-shard
+    (check_vma off).  Nothing here may introduce a cross-shard
     collective — each kernel sees only its replica's `[lanes_per_replica,
     ...]` slab, which is what keeps a 1-replica mesh bit-identical to the
     unsharded step.
@@ -50,8 +51,16 @@ from repro.kernels import softmax_entropy as _sm_k
 from repro.kernels import span_attention as _span_k
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def interpret_mode() -> bool:
+    """True on the CPU backend, False on the TPU; any other backend raises
+    rather than silently running the kernels in the Python interpreter."""
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"Pallas kernels run on the TPU, or interpreted on the CPU; "
+            f"the default backend is {backend!r}"
+        )
+    return backend == "cpu"
 
 
 # ---------------------------------------------------------------------------
@@ -64,7 +73,7 @@ def layernorm(x: jnp.ndarray, scale: jnp.ndarray, bias: jnp.ndarray,
     """Fused two-moment LayerNorm over the last axis; any leading shape."""
     shape = x.shape
     out = _ln_k.layernorm(
-        x.reshape(-1, shape[-1]), scale, bias, eps=eps, interpret=_interpret()
+        x.reshape(-1, shape[-1]), scale, bias, eps=eps, interpret=interpret_mode()
     )
     return out.reshape(shape).astype(x.dtype)
 
@@ -83,7 +92,7 @@ def entropy(logits: jnp.ndarray) -> jnp.ndarray:
     """
     shape = logits.shape
     x2 = logits.reshape(-1, shape[-1])
-    _, h = _sm_k.softmax_entropy(x2, jnp.ones_like(x2), interpret=_interpret())
+    _, h = _sm_k.softmax_entropy(x2, jnp.ones_like(x2), interpret=interpret_mode())
     return h.reshape(shape[:-1])
 
 
@@ -92,11 +101,13 @@ def entropy(logits: jnp.ndarray) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 
 
-def act_quantize(x: jnp.ndarray, n_bits: int, n_exp: int) -> jnp.ndarray:
+def act_quantize(
+    x: jnp.ndarray, n_bits: int, n_exp: int, amax: Optional[jnp.ndarray] = None
+) -> jnp.ndarray:
     shape = x.shape
     x2 = x.reshape(-1, shape[-1]) if x.ndim > 1 else x.reshape(1, -1)
     out = adaptivfloat_k.quantize(
-        x2, fmt=AFFormat(n_bits, n_exp), interpret=_interpret()
+        x2, fmt=AFFormat(n_bits, n_exp), amax=amax, interpret=interpret_mode()
     )
     return out.reshape(shape).astype(x.dtype)
 
@@ -136,7 +147,7 @@ def dense_attention(
         )
     out = _span_k.span_attention(
         qh, kh, vh, spans, Sk,
-        causal=causal, bq=bq, bk=bk, interpret=_interpret(), kv_lens=kvl,
+        causal=causal, bq=bq, bk=bk, interpret=interpret_mode(), kv_lens=kvl,
     )
     return out.reshape(B, H, Sq, dh).transpose(0, 2, 1, 3).astype(q.dtype)
 
@@ -150,14 +161,19 @@ BlockMask = Tuple[np.ndarray, int, int]
 
 
 def _block_size(dim: int, want: int) -> int:
-    b = min(want, dim)
-    while dim % b:
-        b -= 1
-    return b
+    """Largest multiple of 128 that divides ``dim`` and is at most
+    ``want``, else ``dim`` itself.  Mosaic needs each of a block's last two
+    dimensions to be a multiple of (8, 128) or the whole array dimension;
+    the weight tile's K side is the activation tile's lane dimension, so
+    both sides of the tile follow the 128 rule."""
+    for b in range(min(want, dim) // 128 * 128, 0, -128):
+        if dim % b == 0:
+            return b
+    return dim
 
 
 def mlp_block_masks(
-    mlp_params: Dict[str, Any], bk: int = 32, bn: int = 32
+    mlp_params: Dict[str, Any], bk: int = 128, bn: int = 128
 ) -> Dict[str, Optional[BlockMask]]:
     """Host-side static occupancy masks for each MLP weight matrix.
 
@@ -186,6 +202,6 @@ def sparse_matmul(x: jnp.ndarray, w: jnp.ndarray, mask: BlockMask) -> jnp.ndarra
     shape = x.shape
     x2 = x.reshape(-1, shape[-1])
     out = block_sparse.block_sparse_matmul(
-        x2, w, occ, bm=128, bk=bk_, bn=bn_, interpret=_interpret()
+        x2, w, occ, bm=128, bk=bk_, bn=bn_, interpret=interpret_mode()
     )
     return out.reshape(*shape[:-1], w.shape[1]).astype(x.dtype)

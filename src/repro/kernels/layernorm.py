@@ -31,7 +31,7 @@ def layernorm(
     *,
     eps: float = 1e-6,
     block_rows: int = 256,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """x: [rows, d] (callers flatten leading dims)."""
     rows, d = x.shape

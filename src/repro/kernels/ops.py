@@ -1,10 +1,10 @@
 """jit'd dispatch wrappers around the Pallas kernels.
 
-On CPU (this container) kernels run in interpret mode — the kernel body
-executes in Python for correctness validation; on TPU the same code emits
-Mosaic.  `span_attention_op` implements the full EdgeBERT deploy path: dead
-heads (span 0) are gathered out of the graph, survivors run the windowed
-kernel bucketed by span.
+On the CPU backend kernels run in interpret mode — the kernel body executes
+in Python for correctness validation; on TPU the same code emits Mosaic
+(``dispatch.interpret_mode``).  `span_attention_op` implements the full
+EdgeBERT deploy path: dead heads (span 0) are gathered out of the graph,
+survivors run the windowed kernel bucketed by span.
 """
 from __future__ import annotations
 
@@ -17,18 +17,14 @@ import numpy as np
 
 from repro.core.adaptivfloat import AFFormat
 from repro.core.adaptive_span import active_head_indices
-from repro.kernels import adaptivfloat_k, block_sparse, layernorm, softmax_entropy, span_attention
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+from repro.kernels import adaptivfloat_k, block_sparse, dispatch, layernorm, softmax_entropy, span_attention
 
 
 @functools.partial(jax.jit, static_argnames=("eps",))
 def layernorm_op(x: jnp.ndarray, gamma: jnp.ndarray, beta: jnp.ndarray, eps: float = 1e-6):
     shape = x.shape
     x2 = x.reshape(-1, shape[-1])
-    out = layernorm.layernorm(x2, gamma, beta, eps=eps, interpret=_interpret())
+    out = layernorm.layernorm(x2, gamma, beta, eps=eps, interpret=dispatch.interpret_mode())
     return out.reshape(shape)
 
 
@@ -55,7 +51,7 @@ def softmax_entropy_op(logits: jnp.ndarray, mask: Optional[jnp.ndarray] = None):
             f"mask shape {mask.shape} must match logits shape {logits.shape}"
         )
         mask = mask.reshape(-1, shape[-1])
-    p, h = softmax_entropy.softmax_entropy(x2, mask, interpret=_interpret())
+    p, h = softmax_entropy.softmax_entropy(x2, mask, interpret=dispatch.interpret_mode())
     return p.reshape(shape), h.reshape(shape[:-1])
 
 
@@ -63,7 +59,7 @@ def softmax_entropy_op(logits: jnp.ndarray, mask: Optional[jnp.ndarray] = None):
 def af_quantize_op(x: jnp.ndarray, n_bits: int = 8, n_exp: int = 3):
     shape = x.shape
     x2 = x.reshape(-1, shape[-1]) if x.ndim > 1 else x.reshape(1, -1)
-    out = adaptivfloat_k.quantize(x2, fmt=AFFormat(n_bits, n_exp), interpret=_interpret())
+    out = adaptivfloat_k.quantize(x2, fmt=AFFormat(n_bits, n_exp), interpret=dispatch.interpret_mode())
     return out.reshape(shape)
 
 
@@ -71,14 +67,14 @@ def af_quantize_op(x: jnp.ndarray, n_bits: int = 8, n_exp: int = 3):
 def af_matmul_op(x: jnp.ndarray, w_codes: jnp.ndarray, e_min: jnp.ndarray,
                  n_bits: int = 8, n_exp: int = 3):
     return adaptivfloat_k.af_matmul(
-        x, w_codes, e_min, fmt=AFFormat(n_bits, n_exp), interpret=_interpret()
+        x, w_codes, e_min, fmt=AFFormat(n_bits, n_exp), interpret=dispatch.interpret_mode()
     )
 
 
 def block_sparse_matmul_op(x, w, block_mask, bk: int = 128, bn: int = 128):
     """block_mask must be a STATIC numpy occupancy array (deploy-time masks)."""
     return block_sparse.block_sparse_matmul(
-        x, w, np.asarray(block_mask), bk=bk, bn=bn, interpret=_interpret()
+        x, w, np.asarray(block_mask), bk=bk, bn=bn, interpret=dispatch.interpret_mode()
     )
 
 
@@ -124,7 +120,7 @@ def span_attention_op(
             causal=causal,           # still mask element-wise in the kernel
             bq=bq,
             bk=bk,
-            interpret=_interpret(),
+            interpret=dispatch.interpret_mode(),
         ).reshape(B, H, Sq, dh)
         return out.transpose(0, 2, 1, 3)
 
@@ -150,7 +146,7 @@ def span_attention_op(
         causal=causal,
         bq=bq,
         bk=bk,
-        interpret=_interpret(),
+        interpret=dispatch.interpret_mode(),
     ).reshape(B, Ha, Sq, dh)
 
     full = jnp.zeros((B, H, Sq, dh), q.dtype)

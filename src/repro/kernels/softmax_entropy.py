@@ -35,7 +35,7 @@ def softmax_entropy(
     mask: jnp.ndarray,            # [rows, n] (ones for pure softmax)
     *,
     block_rows: int = 256,
-    interpret: bool = True,
+    interpret: bool,
 ):
     rows, n = logits.shape
     block_rows = min(block_rows, rows)

@@ -36,8 +36,8 @@ def _span_attn_kernel(
     k_ref,               # [1, bk, dh]
     v_ref,               # [1, bk, dh]
     o_ref,               # [1, bq, dh]
-    m_ref,               # VMEM [bq]
-    l_ref,               # VMEM [bq]
+    m_ref,               # VMEM [bq, 1] running max (2-D: Mosaic lays out
+    l_ref,               # VMEM [bq, 1] running sum  rows, not 1-D vectors)
     acc_ref,             # VMEM [bq, dh]
     *,
     bq: int,
@@ -68,7 +68,9 @@ def _span_attn_kernel(
     def _step():
         q = q_ref[0].astype(jnp.float32) * scale            # [bq, dh]
         k = k_ref[0].astype(jnp.float32)                    # [bk, dh]
-        scores = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # [bq, bk]
+        scores = jax.lax.dot_general(                       # [bq, bk] = q k^T
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        )
 
         q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         k_pos = k_blk * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
@@ -84,22 +86,22 @@ def _span_attn_kernel(
         ok = ok & (k_pos < kvl) & (k_pos < sk) & (q_pos < sq)
         scores = jnp.where(ok, scores, NEG_INF)
 
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1))
+        m_prev = m_ref[...]                                 # [bq, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
         corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(scores - m_new[:, None])
+        p = jnp.exp(scores - m_new)
         p = jnp.where(ok, p, 0.0)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jnp.dot(
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jnp.dot(
             p, v_ref[0].astype(jnp.float32), preferred_element_type=jnp.float32
         )
         m_ref[...] = m_new
 
     @pl.when(s == n_s - 1)
     def _emit():
-        l = l_ref[...]
-        out = acc_ref[...] / jnp.maximum(l, 1e-20)[:, None]
-        out = jnp.where((l > 0.0)[:, None], out, 0.0)
+        l = l_ref[...]                                      # [bq, 1]
+        out = acc_ref[...] / jnp.maximum(l, 1e-20)
+        out = jnp.where(l > 0.0, out, 0.0)
         o_ref[0] = out.astype(o_ref.dtype)
 
 
@@ -129,7 +131,7 @@ def span_attention(
     causal: bool,
     bq: int = 128,
     bk: int = 128,
-    interpret: bool = True,
+    interpret: bool,
     kv_lens: jnp.ndarray = None,  # [BH] int32 valid keys per row (right-
                                   # padded inputs); None = all Sk keys valid
 ) -> jnp.ndarray:
@@ -185,8 +187,8 @@ def span_attention(
             ],
             out_specs=pl.BlockSpec((1, bq_, dh), q_index),
             scratch_shapes=[
-                pltpu.VMEM((bq_,), jnp.float32),
-                pltpu.VMEM((bq_,), jnp.float32),
+                pltpu.VMEM((bq_, 1), jnp.float32),
+                pltpu.VMEM((bq_, 1), jnp.float32),
                 pltpu.VMEM((bq_, dh), jnp.float32),
             ],
         ),

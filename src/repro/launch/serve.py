@@ -13,6 +13,7 @@ import time
 import jax
 import numpy as np
 
+from repro.common.compilation import setup_compile_cache
 from repro.common.util import logger
 from repro.configs.base import get_config, get_smoke_config
 from repro.data.synthetic import SyntheticCLS, SyntheticLM
@@ -31,6 +32,7 @@ def main():
     ap.add_argument("--max-new-tokens", type=int, default=8)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    setup_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     cfg = dataclasses.replace(cfg, dtype="float32", remat_policy="none")

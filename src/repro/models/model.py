@@ -226,15 +226,27 @@ class Model:
             o["offramp_pooler_w"], o["offramp_pooler_b"], o["offramp_cls_w"], o["offramp_cls_b"]
         )
 
-    def _maybe_actquant(self, h: jnp.ndarray, use_pallas: bool = False) -> jnp.ndarray:
-        q = self.cfg.edgebert.quant
-        if q.enabled and q.quantize_activations:
-            if use_pallas:
-                from repro.kernels import dispatch
+    def _maybe_actquant(
+        self, h: jnp.ndarray, use_pallas: bool = False, kv_len=None
+    ) -> jnp.ndarray:
+        """AdaptivFloat activation fake-quant with a per-tensor bias.
 
-                return dispatch.act_quantize(h, q.n_bits, q.n_exp)
-            return fake_quant(h, AFFormat(q.n_bits, q.n_exp))
-        return h
+        With ``kv_len`` (a right-padded ``[..., S, D]`` lane) the bias comes
+        from the first ``kv_len`` positions only, so bucket padding cannot
+        move the quantization grid of the sentence's own tokens.
+        """
+        q = self.cfg.edgebert.quant
+        if not (q.enabled and q.quantize_activations):
+            return h
+        amax = None
+        if kv_len is not None:
+            valid = jnp.arange(h.shape[-2]) < kv_len
+            amax = jnp.max(jnp.where(valid[:, None], jnp.abs(h.astype(jnp.float32)), 0.0))
+        if use_pallas:
+            from repro.kernels import dispatch
+
+            return dispatch.act_quantize(h, q.n_bits, q.n_exp, amax=amax)
+        return fake_quant(h, AFFormat(q.n_bits, q.n_exp), amax=amax)
 
     def _sp_constrain(self, h: jnp.ndarray) -> jnp.ndarray:
         """Sequence-parallel residual stream: [B, S, D] sharded (batch->dp,
@@ -302,7 +314,7 @@ class Model:
                     use_pallas=use_pallas, block_masks=block_masks,
                 )
             h = self._sp_constrain(h + mo)
-        return self._maybe_actquant(h, use_pallas=use_pallas), aux, cache
+        return self._maybe_actquant(h, use_pallas=use_pallas, kv_len=kv_len), aux, cache
 
     def _cross_layer_step(self, lp: Params, h, img, cache_kv=None):
         """Gated cross-attention layer (llama-3.2-vision style)."""

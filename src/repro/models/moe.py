@@ -16,7 +16,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.common.jax_compat import shard_map
 from repro.models.layers import dense_init
 
 Params = Dict[str, Any]
@@ -127,7 +126,7 @@ def apply_moe_shardmap(
             aux = jax.lax.pmean(aux, ba if len(ba) > 1 else ba[0])
         return y.reshape(B_l, S, d), aux
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(

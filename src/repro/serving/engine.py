@@ -68,6 +68,7 @@ regressions fail loudly in tests and CI.
 """
 from __future__ import annotations
 
+import functools
 import itertools
 import time
 from dataclasses import dataclass, field
@@ -159,6 +160,18 @@ def _expand_arbiters(arbiter, replicas: int) -> list:
     return [arbiter] + [
         BatchedDVFSArbiter(arbiter.c) for _ in range(replicas - 1)
     ]
+
+
+def _on_lanes(mesh, tree, lane_axis: int):
+    """Commit bucket state to the mesh's lane sharding (``lane_axis`` split
+    over ``"data"``) when it is created.  The sharded fused step returns its
+    state with that sharding, so state that started uncommitted on one
+    device would hand the step's jit a second input sharding on the next
+    call — a second compile per (bucket, replica).  No-op without a mesh."""
+    if mesh is None:
+        return tree
+    spec = jax.sharding.PartitionSpec(*([None] * lane_axis), "data")
+    return jax.device_put(tree, jax.sharding.NamedSharding(mesh, spec))
 
 
 def _resolve_mesh(replicas: int, mesh):
@@ -372,8 +385,9 @@ class ClassifierServer:
             self._traces["insert"][S] = self._traces["insert"].get(S, 0) + 1
             return step_math.lane_insert(h, lane, h_new)
 
-        self._embed = jax.jit(embed_fn)
-        self._step = jax.jit(step_fn)
+        jit = functools.partial(step_math.jit_at_config_precision, model.cfg)
+        self._embed = jit(embed_fn)
+        self._step = jit(step_fn)
         self._insert = jax.jit(insert_fn)
 
     # ---------------------------------------------------------- DVFS helpers
@@ -479,7 +493,9 @@ class ClassifierServer:
         D = self.cfg.d_model
         dtype = jnp.asarray(self.params["embed"]["tok"]).dtype
         self._bstate[bucket] = {
-            "h": jnp.zeros((self.lanes, bucket, D), dtype),
+            "h": _on_lanes(
+                self._mesh, jnp.zeros((self.lanes, bucket, D), dtype), 0
+            ),
             "len": np.full(self.lanes, bucket, np.int32),
             "out": None,
         }
@@ -930,10 +946,11 @@ class DecoderServer:
                 use_pallas=self.use_pallas,
             )
 
-        self._decode = jax.jit(decode_fn, static_argnums=(4,))
-        self._decode_ee = jax.jit(decode_ee_fn, static_argnums=(5,))
-        self._decode_spec = jax.jit(decode_spec_fn, static_argnums=(5,))
-        self._prefill = jax.jit(prefill_fn)
+        jit = functools.partial(step_math.jit_at_config_precision, model.cfg)
+        self._decode = jit(decode_fn, static_argnums=(4,))
+        self._decode_ee = jit(decode_ee_fn, static_argnums=(5,))
+        self._decode_spec = jit(decode_spec_fn, static_argnums=(5,))
+        self._prefill = jit(prefill_fn)
 
     # ---------------------------------------------------------- DVFS helpers
     @property
@@ -1073,7 +1090,9 @@ class DecoderServer:
 
     def bucket_begin(self, bucket: int) -> None:
         self._bstate[bucket] = {
-            "cache": self.model.init_cache(self.lanes, bucket),
+            "cache": _on_lanes(
+                self._mesh, self.model.init_cache(self.lanes, bucket), 1
+            ),
             "pos": np.zeros(self.lanes, np.int32),
             "cur": np.zeros((self.lanes, 1), np.int32),
             "reqs": [None] * self.lanes,

@@ -33,7 +33,8 @@ unsharded step — the parity guarantee the serving tests gate.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Optional
+import functools
+from typing import Any, Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -42,6 +43,26 @@ from repro.common.jax_compat import shard_map_norep
 from repro.core.early_exit import offramp_logits
 from repro.core.entropy import entropy_from_logits
 from repro.models.model import Model
+
+
+def matmul_precision(cfg) -> str:
+    """The matmul precision a config's dtype states: a float32 model runs
+    float32 matmuls ("highest"; the TPU otherwise rounds f32 operands to
+    one bf16 pass), any narrower dtype the backend default."""
+    return "highest" if jnp.dtype(cfg.dtype) == jnp.float32 else "default"
+
+
+def jit_at_config_precision(cfg, fn: Callable, **jit_kw) -> Callable:
+    """``jax.jit(fn)`` traced under ``matmul_precision(cfg)`` — the one
+    place the served steps get their precision, kernels included."""
+    precision = matmul_precision(cfg)
+
+    @functools.wraps(fn)
+    def at_precision(*args, **kwargs):
+        with jax.default_matmul_precision(precision):
+            return fn(*args, **kwargs)
+
+    return jax.jit(at_precision, **jit_kw)
 
 
 # ---------------------------------------------------------------------------

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.common.jax_compat import shard_map
 
 
 def pipeline_forward(
@@ -75,7 +74,7 @@ def pipeline_forward(
         mask = (stage_id == n_stages - 1).astype(outs.dtype)
         return jax.lax.psum(outs * mask, axis)
 
-    return shard_map(
+    return jax.shard_map(
         stage_prog,
         mesh=mesh,
         in_specs=(P(axis), P()),

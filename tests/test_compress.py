@@ -5,7 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.common.jax_compat import make_auto_mesh, shard_map
+from repro.common.jax_compat import make_auto_mesh
 from repro.training.compress import EFState, compressed_psum, ef_init
 
 
@@ -18,7 +18,7 @@ def test_error_feedback_accumulates():
     ef = ef_init(g)
 
     def run(g, ef):
-        return shard_map(
+        return jax.shard_map(
             lambda gg: compressed_psum(gg, ef, "dp", 1),
             mesh=_dp_mesh(),
             in_specs=(jax.sharding.PartitionSpec(),),
@@ -53,7 +53,7 @@ def test_convergence_parity():
         w_plain = w_plain - lr * g_plain
 
         g = {"w": jax.grad(loss)(w_comp)}
-        out, ef = shard_map(
+        out, ef = jax.shard_map(
             lambda gg: compressed_psum(gg, ef, "dp", 1),
             mesh=mesh, in_specs=(P(),), out_specs=(P(), P()),
         )(g)
@@ -73,7 +73,7 @@ def test_wire_payload_is_int8():
         return out, ef2
 
     jaxpr = jax.make_jaxpr(
-        lambda gg: shard_map(
+        lambda gg: jax.shard_map(
             fake,
             mesh=_dp_mesh(),
             in_specs=(jax.sharding.PartitionSpec(),),

@@ -3,11 +3,8 @@
 These run in SUBPROCESSES because the device count must be set before jax
 initializes (the main test process keeps the single real CPU device).
 
-Marked ``multidevice`` and capability-gated: the subprocess snippets need a
-jax with the modern sharding API (``jax.sharding.AxisType``); gating on the
-capability (not the main process's device count — forcing host devices in
-the subprocess works on single-device hosts) keeps these running wherever
-they CAN run.
+Marked ``multidevice``; forcing host devices in the subprocess works on
+single-device hosts.
 """
 import json
 import os
@@ -17,16 +14,7 @@ import textwrap
 
 import pytest
 
-from repro.common.jax_compat import HAS_AXIS_TYPES
-
-pytestmark = [
-    pytest.mark.multidevice,
-    pytest.mark.skipif(
-        not HAS_AXIS_TYPES,
-        reason="installed jax lacks jax.sharding.AxisType, which the "
-        "forced-multi-device subprocess snippets require",
-    ),
-]
+pytestmark = pytest.mark.multidevice
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 

@@ -1,4 +1,5 @@
-"""Per-kernel allclose sweeps vs the ref.py oracles (interpret mode on CPU).
+"""Per-kernel allclose sweeps vs the ref.py oracles (interpret mode on CPU;
+``tests/test_tpu_compile.py`` compiles the same kernels for a v5e).
 
 Every Pallas kernel is swept over shapes (incl. non-multiples forcing padding)
 and dtypes; hypothesis drives the AdaptivFloat property sweep.
@@ -29,7 +30,7 @@ class TestLayerNorm:
     def test_matches_ref(self, rows, d, dtype):
         x = _r((rows, d), 1, dtype, 3.0)
         g, b = _r((d,), 2), _r((d,), 3)
-        got = layernorm(x, g, b, block_rows=64)
+        got = layernorm(x, g, b, block_rows=64, interpret=True)
         want = ref.layernorm(x, g, b)
         atol = 1e-5 if dtype == jnp.float32 else 0.05
         np.testing.assert_allclose(
@@ -44,7 +45,7 @@ class TestSoftmaxEntropy:
         mask = (jax.random.uniform(jax.random.PRNGKey(5), (rows, n)) > 0.3).astype(
             jnp.float32
         )
-        p1, h1 = softmax_entropy(x, mask, block_rows=32)
+        p1, h1 = softmax_entropy(x, mask, block_rows=32, interpret=True)
         p2, h2 = ref.softmax_entropy(x, mask)
         np.testing.assert_allclose(np.asarray(p1), np.asarray(p2), atol=1e-6)
         np.testing.assert_allclose(np.asarray(h1), np.asarray(h2), atol=1e-6)
@@ -53,7 +54,7 @@ class TestSoftmaxEntropy:
         from repro.core.entropy import entropy_from_logits
 
         x = _r((64, 16), 6, scale=8.0)
-        _, h = softmax_entropy(x, jnp.ones_like(x))
+        _, h = softmax_entropy(x, jnp.ones_like(x), interpret=True)
         np.testing.assert_allclose(
             np.asarray(h), np.asarray(entropy_from_logits(x)), atol=1e-5
         )
@@ -64,7 +65,7 @@ class TestAFQuantKernel:
     def test_matches_ref(self, n_bits, scale):
         fmt = AFFormat(n_bits, 3)
         x = _r((100, 32), n_bits, scale=scale)
-        got = quantize(x, fmt=fmt, block_rows=32)
+        got = quantize(x, fmt=fmt, block_rows=32, interpret=True)
         want = ref.adaptivfloat_quantize(x, fmt)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0, atol=0)
 
@@ -75,7 +76,7 @@ class TestAFMatmul:
         w = _r((k, n), 7, scale=2.0)
         codes, e_min = af_encode(w)
         x = _r((m, k), 8)
-        got = af_matmul(x, codes, e_min, bm=32, bk=32, bn=32)
+        got = af_matmul(x, codes, e_min, bm=32, bk=32, bn=32, interpret=True)
         want = ref.af_matmul(x, codes, e_min)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-4)
 
@@ -89,7 +90,8 @@ class TestBlockSparse:
         full = np.repeat(np.repeat(bmask, bk, 0), bn, 1)
         w = jnp.asarray(rng.normal(size=(K, N)) * full, jnp.float32)
         x = _r((48, K), 10)
-        got = block_sparse_matmul(x, w, bmask, bm=16, bk=bk, bn=bn)
+        got = block_sparse_matmul(x, w, bmask, bm=16, bk=bk, bn=bn,
+                                  interpret=True)
         want = ref.block_sparse_matmul(x, w, jnp.asarray(bmask), bk, bn)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-4)
 
@@ -118,7 +120,7 @@ class TestSpanAttention:
         ve = jnp.repeat(v, G, axis=1).reshape(B * H, S, dh)
         got = span_attention(
             q.reshape(B * H, S, dh), ke, ve, jnp.tile(spans, B), window,
-            causal=causal, bq=32, bk=32,
+            causal=causal, bq=32, bk=32, interpret=True,
         ).reshape(B, H, S, dh)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
 
@@ -172,7 +174,8 @@ class TestSpanAttention:
         spans = jnp.full((BH,), window, jnp.int32)
         kvl = 23
         got = span_attention(q, k, v, spans, window, causal=causal, bq=32,
-                             bk=32, kv_lens=jnp.full((BH,), kvl, jnp.int32))
+                             bk=32, interpret=True,
+                             kv_lens=jnp.full((BH,), kvl, jnp.int32))
         # oracle: the first kvl query rows of the padded run must equal a run
         # on the physically truncated arrays (rows past kvl are padding)
         want = ref.span_attention(
@@ -189,7 +192,8 @@ class TestSpanAttention:
                 return span_attention(
                     ql[None], kl[None], vl[None],
                     jnp.full((1,), window, jnp.int32), window,
-                    causal=causal, bq=32, bk=32, kv_lens=n[None],
+                    causal=causal, bq=32, bk=32, interpret=True,
+                    kv_lens=n[None],
                 )[0]
             return jax.vmap(one)(q, k, v, lens)
 

@@ -7,7 +7,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.common.jax_compat import HAS_AXIS_TYPES
 from repro.configs.base import get_smoke_config
 from repro.models.model import build_model
 from repro.models import moe
@@ -98,11 +97,6 @@ def test_hybrid_grouped_equals_cond():
 
 
 @pytest.mark.multidevice
-@pytest.mark.skipif(
-    not HAS_AXIS_TYPES,
-    reason="installed jax lacks jax.sharding.AxisType (needed by "
-    "set_mesh in the forced-multi-device subprocess)",
-)
 def test_moe_shardmap_matches_dense():
     """Explicit shard_map EP dispatch (§Perf qwen3 A5) is bit-exact vs the
     dense reference under generous capacity (subprocess: multi-device)."""

@@ -7,16 +7,7 @@ import textwrap
 
 import pytest
 
-from repro.common.jax_compat import HAS_AXIS_TYPES
-
-pytestmark = [
-    pytest.mark.multidevice,
-    pytest.mark.skipif(
-        not HAS_AXIS_TYPES,
-        reason="installed jax lacks jax.sharding.AxisType, which the "
-        "forced-multi-device subprocess snippet requires",
-    ),
-]
+pytestmark = pytest.mark.multidevice
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 

@@ -47,6 +47,23 @@ class TestClassifierServer:
             assert np.argmax(req.result) == np.argmax(want)
             np.testing.assert_allclose(req.result, want, atol=5e-2)
 
+    def test_padding_rows_do_not_move_actquant_bias(self):
+        """A lane right-padded to its bucket must quantize its activations
+        on the grid of its own tokens: the AdaptivFloat bias comes from the
+        first ``kv_len`` rows, not from the padding."""
+        model, params, cfg = _albert_model()
+        assert cfg.edgebert.quant.enabled and cfg.edgebert.quant.quantize_activations
+        L, S, D = 10, 16, cfg.d_model
+        h = jax.random.normal(jax.random.PRNGKey(3), (1, L, D))
+        padded = jnp.concatenate([h, jnp.full((1, S - L, D), 100.0)], axis=1)
+        want = model._maybe_actquant(h)
+        assert not np.array_equal(                   # padding would move it
+            np.asarray(model._maybe_actquant(padded)[:, :L]), np.asarray(want)
+        )
+        for use_pallas in (False, True):
+            got = model._maybe_actquant(padded, use_pallas=use_pallas, kv_len=L)
+            np.testing.assert_array_equal(np.asarray(got[:, :L]), np.asarray(want))
+
     def test_layer_calls_reflect_early_exit(self):
         """Continuation batching: total layer computations ~ sum(exit layers),
         NOT n_sentences * n_layers — the throughput form of Fig. 4 savings."""

@@ -106,6 +106,26 @@ class TestOneReplicaParity:
             assert t_shd[k] == t_ref[k], k
         assert t_shd["replicas"] == 1
 
+    def test_classifier_sharded_r1_one_compile_per_bucket(self):
+        """Counted from JAX's compile events, not the engine's counters:
+        one fused-step compile per bucket, none in a second drain."""
+        from repro.common.compilation import CompileCounter
+
+        model, params, cfg = _albert_model(threshold=0.5)
+        batch = SyntheticCLS(cfg.vocab_size, 32, 8, num_classes=3, seed=0).batch(0)
+        shd = ClassifierServer(
+            model, params, batch_lanes=2, buckets=(16, 32), mesh=_mesh1()
+        )
+        drains = []
+        for rnd in range(2):
+            with CompileCounter() as cc:
+                for i, L in enumerate((10, 16, 24, 32)):
+                    shd.submit(Request(uid=10 * rnd + i, tokens=batch["tokens"][i][:L]))
+                shd.run()
+            drains.append(cc)
+        assert drains[0].by_name["jit(step_fn)"] == 2
+        assert drains[1].count == 0, drains[1].by_name
+
     def test_classifier_sharded_r1_pallas_eligible(self):
         """The Pallas-dispatch path must stay eligible INSIDE shard_map
         (pallas_call has no replication rule — shard_map_norep turns the

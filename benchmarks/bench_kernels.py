@@ -14,9 +14,9 @@ import numpy as np
 from benchmarks.common import emit, time_us
 from repro.core.adaptivfloat import af_encode
 from repro.kernels import ref
-from repro.kernels.dispatch import interpret_mode
 from repro.kernels.adaptivfloat_k import af_matmul, quantize
 from repro.kernels.block_sparse import block_sparse_matmul
+from repro.kernels.dispatch import interpret_mode
 from repro.kernels.layernorm import layernorm
 from repro.kernels.softmax_entropy import softmax_entropy
 from repro.kernels.span_attention import span_attention

@@ -25,8 +25,8 @@ naming the device.
 from __future__ import annotations
 
 import argparse
-import collections
 import base64
+import collections
 import dataclasses
 import json
 import os

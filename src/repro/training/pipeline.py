@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 
-
 def pipeline_forward(
     layer_fn: Callable[[Any, jnp.ndarray], jnp.ndarray],
     stage_params: Any,          # pytree with leading [n_stages, ...] axis

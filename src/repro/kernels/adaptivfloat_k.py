@@ -81,6 +81,7 @@ def quantize(
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         interpret=interpret,
+        name="af_quant",   # the kernel's name in compiled HLO and traces
     )(x, e_min.reshape(1, 1))
     return out[:rows]
 

@@ -11,7 +11,7 @@ HBM and never touch the MXU — compute AND memory traffic scale with density.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -67,6 +67,7 @@ def block_sparse_matmul(
     bk: int = 128,
     bn: int = 128,
     interpret: bool,
+    name: Optional[str] = None,   # the kernel's name in compiled HLO and traces
 ) -> jnp.ndarray:
     M, K = x.shape
     K2, N = w.shape
@@ -95,5 +96,6 @@ def block_sparse_matmul(
         ),
         out_shape=jax.ShapeDtypeStruct((Mp, N), jnp.float32),
         interpret=interpret,
+        name=name,
     )(jnp.asarray(indices), jnp.asarray(counts), x, w)
     return out[:M]

@@ -15,7 +15,11 @@ Rules the dispatchers obey (the engine's compile guarantees depend on them):
   * `interpret_mode()` decides how every kernel runs: compiled by Mosaic
     on the TPU, interpreted on the CPU backend (the same `pallas_call`s
     execute their bodies in Python, so CPU tests exercise the exact kernel
-    code that Mosaic compiles), and an error on any other backend.
+    code that Mosaic compiles), and an error on any other backend;
+  * every kernel runs under a stable name, its `pallas_call`'s `name` and a
+    `jax.named_scope` around its glue (reshapes, padding): the compiled HLO
+    and the device trace call it `bs_mlp_up`, `bs_mlp_down`, `layernorm`,
+    `offramp_entropy`, `af_quant` or `span_attn`.
 
 Eligibility notes:
   * soft (trained) spans taper probabilities over a ramp; the hard-window
@@ -72,10 +76,11 @@ def layernorm(x: jnp.ndarray, scale: jnp.ndarray, bias: jnp.ndarray,
               *, eps: float = 1e-6) -> jnp.ndarray:
     """Fused two-moment LayerNorm over the last axis; any leading shape."""
     shape = x.shape
-    out = _ln_k.layernorm(
-        x.reshape(-1, shape[-1]), scale, bias, eps=eps, interpret=interpret_mode()
-    )
-    return out.reshape(shape).astype(x.dtype)
+    with jax.named_scope("layernorm"):
+        out = _ln_k.layernorm(
+            x.reshape(-1, shape[-1]), scale, bias, eps=eps, interpret=interpret_mode()
+        )
+        return out.reshape(shape).astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -91,9 +96,10 @@ def entropy(logits: jnp.ndarray) -> jnp.ndarray:
     attention, via kv_len) — see `ops.softmax_entropy_op`.
     """
     shape = logits.shape
-    x2 = logits.reshape(-1, shape[-1])
-    _, h = _sm_k.softmax_entropy(x2, jnp.ones_like(x2), interpret=interpret_mode())
-    return h.reshape(shape[:-1])
+    with jax.named_scope("offramp_entropy"):
+        x2 = logits.reshape(-1, shape[-1])
+        _, h = _sm_k.softmax_entropy(x2, jnp.ones_like(x2), interpret=interpret_mode())
+        return h.reshape(shape[:-1])
 
 
 # ---------------------------------------------------------------------------
@@ -105,11 +111,12 @@ def act_quantize(
     x: jnp.ndarray, n_bits: int, n_exp: int, amax: Optional[jnp.ndarray] = None
 ) -> jnp.ndarray:
     shape = x.shape
-    x2 = x.reshape(-1, shape[-1]) if x.ndim > 1 else x.reshape(1, -1)
-    out = adaptivfloat_k.quantize(
-        x2, fmt=AFFormat(n_bits, n_exp), amax=amax, interpret=interpret_mode()
-    )
-    return out.reshape(shape).astype(x.dtype)
+    with jax.named_scope("af_quant"):
+        x2 = x.reshape(-1, shape[-1]) if x.ndim > 1 else x.reshape(1, -1)
+        out = adaptivfloat_k.quantize(
+            x2, fmt=AFFormat(n_bits, n_exp), amax=amax, interpret=interpret_mode()
+        )
+        return out.reshape(shape).astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -136,20 +143,21 @@ def dense_attention(
     B, Sq, H, dh = q.shape
     Sk, KV = k.shape[1], k.shape[2]
     G = H // KV
-    qh = q.transpose(0, 2, 1, 3).reshape(B * H, Sq, dh)
-    kh = jnp.repeat(k.transpose(0, 2, 1, 3), G, axis=1).reshape(B * H, Sk, dh)
-    vh = jnp.repeat(v.transpose(0, 2, 1, 3), G, axis=1).reshape(B * H, Sk, dh)
-    spans = jnp.full((B * H,), Sk, jnp.int32)
-    kvl = None
-    if kv_len is not None:
-        kvl = jnp.broadcast_to(
-            jnp.asarray(kv_len, jnp.int32).reshape(()), (B * H,)
+    with jax.named_scope("span_attn"):
+        qh = q.transpose(0, 2, 1, 3).reshape(B * H, Sq, dh)
+        kh = jnp.repeat(k.transpose(0, 2, 1, 3), G, axis=1).reshape(B * H, Sk, dh)
+        vh = jnp.repeat(v.transpose(0, 2, 1, 3), G, axis=1).reshape(B * H, Sk, dh)
+        spans = jnp.full((B * H,), Sk, jnp.int32)
+        kvl = None
+        if kv_len is not None:
+            kvl = jnp.broadcast_to(
+                jnp.asarray(kv_len, jnp.int32).reshape(()), (B * H,)
+            )
+        out = _span_k.span_attention(
+            qh, kh, vh, spans, Sk,
+            causal=causal, bq=bq, bk=bk, interpret=interpret_mode(), kv_lens=kvl,
         )
-    out = _span_k.span_attention(
-        qh, kh, vh, spans, Sk,
-        causal=causal, bq=bq, bk=bk, interpret=interpret_mode(), kv_lens=kvl,
-    )
-    return out.reshape(B, H, Sq, dh).transpose(0, 2, 1, 3).astype(q.dtype)
+        return out.reshape(B, H, Sq, dh).transpose(0, 2, 1, 3).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -196,12 +204,15 @@ def mlp_block_masks(
     return masks
 
 
-def sparse_matmul(x: jnp.ndarray, w: jnp.ndarray, mask: BlockMask) -> jnp.ndarray:
-    """x @ w skipping pruned (all-zero) weight tiles; any leading shape."""
+def sparse_matmul(x: jnp.ndarray, w: jnp.ndarray, mask: BlockMask, *,
+                  name: str) -> jnp.ndarray:
+    """x @ w skipping pruned (all-zero) weight tiles; any leading shape.
+    ``name`` names the kernel (``bs_mlp_up``, ``bs_mlp_down``)."""
     occ, bk_, bn_ = mask
     shape = x.shape
-    x2 = x.reshape(-1, shape[-1])
-    out = block_sparse.block_sparse_matmul(
-        x2, w, occ, bm=128, bk=bk_, bn=bn_, interpret=interpret_mode()
-    )
-    return out.reshape(*shape[:-1], w.shape[1]).astype(x.dtype)
+    with jax.named_scope(name):
+        x2 = x.reshape(-1, shape[-1])
+        out = block_sparse.block_sparse_matmul(
+            x2, w, occ, bm=128, bk=bk_, bn=bn_, interpret=interpret_mode(), name=name
+        )
+        return out.reshape(*shape[:-1], w.shape[1]).astype(x.dtype)

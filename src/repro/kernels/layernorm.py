@@ -52,5 +52,6 @@ def layernorm(
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         interpret=interpret,
+        name="layernorm",   # the kernel's name in compiled HLO and traces
     )(x, gamma, beta)
     return out[:rows]

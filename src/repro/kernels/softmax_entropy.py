@@ -61,5 +61,6 @@ def softmax_entropy(
             jax.ShapeDtypeStruct((logits.shape[0],), jnp.float32),
         ],
         interpret=interpret,
+        name="offramp_entropy",   # the kernel's name in compiled HLO and traces
     )(logits, mask)
     return probs[:rows], ent[:rows]

@@ -194,5 +194,6 @@ def span_attention(
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
+        name="span_attn",   # the kernel's name in compiled HLO and traces
     )(meta, q, k, v)
     return out[:, :Sq]

@@ -378,7 +378,9 @@ def apply_mlp(
 ) -> jnp.ndarray:
     def mm(h_, name):
         if use_pallas and block_masks and block_masks.get(name) is not None:
-            return _dispatch().sparse_matmul(h_, p[name], block_masks[name])
+            return _dispatch().sparse_matmul(
+                h_, p[name], block_masks[name], name="bs_mlp_" + name[2:]
+            )
         return h_ @ p[name]
 
     if act == "swiglu":

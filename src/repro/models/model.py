@@ -280,21 +280,25 @@ class Model:
         post_ln = cfg.family == "albert"
         aux = jnp.zeros((), jnp.float32)
         if post_ln:
-            attn_out, cache = L.attention_layer(
-                lp["attn"], h, cfg, causal=causal, positions=positions,
-                span_z=span_z, span_ramp=cfg.edgebert.span.ramp,
-                cache=cache, cache_pos=cache_pos, kv_len=kv_len,
-                use_pallas=use_pallas,
-            )
-            h = L.apply_norm(lp["norm1"], h + attn_out, cfg.norm, use_pallas=use_pallas)
-            if "moe" in lp:
-                mo, aux = moe.apply_moe(lp["moe"], h, cfg)
-            else:
-                mo = L.apply_mlp(
-                    lp["mlp"], h, cfg.act,
-                    use_pallas=use_pallas, block_masks=block_masks,
+            # named phases: the compiled step's ops (and the device trace)
+            # carry "attention" / "mlp" in their scope
+            with jax.named_scope("attention"):
+                attn_out, cache = L.attention_layer(
+                    lp["attn"], h, cfg, causal=causal, positions=positions,
+                    span_z=span_z, span_ramp=cfg.edgebert.span.ramp,
+                    cache=cache, cache_pos=cache_pos, kv_len=kv_len,
+                    use_pallas=use_pallas,
                 )
-            h = L.apply_norm(lp["norm2"], h + mo, cfg.norm, use_pallas=use_pallas)
+                h = L.apply_norm(lp["norm1"], h + attn_out, cfg.norm, use_pallas=use_pallas)
+            with jax.named_scope("mlp"):
+                if "moe" in lp:
+                    mo, aux = moe.apply_moe(lp["moe"], h, cfg)
+                else:
+                    mo = L.apply_mlp(
+                        lp["mlp"], h, cfg.act,
+                        use_pallas=use_pallas, block_masks=block_masks,
+                    )
+                h = L.apply_norm(lp["norm2"], h + mo, cfg.norm, use_pallas=use_pallas)
         else:
             attn_out, cache = L.attention_layer(
                 lp["attn"], L.apply_norm(lp["norm1"], h, cfg.norm, use_pallas=use_pallas),

@@ -70,13 +70,13 @@ from __future__ import annotations
 
 import functools
 import itertools
-import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, TYPE_CHECKING
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.early_exit import (
     PositionBinnedExitCalibrator,
@@ -104,10 +104,6 @@ class Request:
     # decoder early exit: 1-based off-ramp exit depth of each generated token
     # (full depth when per-token exit is disabled)
     token_exit_layers: List[int] = field(default_factory=list)
-    submit_time: float = 0.0            # WALL clock; caller-set only — the
-                                        # scheduler stamps modeled clocks and
-                                        # never mixes the two
-    finish_time: float = 0.0
     bucket: Optional[int] = None        # length bucket the scheduler assigned
     replica: Optional[int] = None       # device replica the request is pinned
                                         # to (admission placement routing);
@@ -127,6 +123,11 @@ class Request:
     admit_s: float = 0.0                      # modeled clock at lane admission
     retire_s: float = 0.0                     # modeled clock at retirement
     seq: int = 0                              # global submission order
+    # ---- wall stamps (``time.perf_counter``), for observability only: no
+    # scheduling, DVFS or admission code reads them ----
+    queued_at: Optional[float] = None         # at submit()
+    admitted_at: Optional[float] = None       # at its FIRST lane admission
+    retired_at: Optional[float] = None        # at retirement
     # per-layer off-ramp entropies observed while the sentence was in flight;
     # the DVFS controller replays this trace through Alg. 1
     entropy_trace: List[float] = field(default_factory=list)
@@ -490,96 +491,110 @@ class ClassifierServer:
         return len(req.tokens)
 
     def bucket_begin(self, bucket: int) -> None:
-        D = self.cfg.d_model
-        dtype = jnp.asarray(self.params["embed"]["tok"]).dtype
-        self._bstate[bucket] = {
-            "h": _on_lanes(
-                self._mesh, jnp.zeros((self.lanes, bucket, D), dtype), 0
-            ),
-            "len": np.full(self.lanes, bucket, np.int32),
-            "out": None,
-        }
+        with TraceAnnotation("engine.bucket_begin", bucket=bucket):
+            D = self.cfg.d_model
+            dtype = jnp.asarray(self.params["embed"]["tok"]).dtype
+            self._bstate[bucket] = {
+                "h": _on_lanes(
+                    self._mesh, jnp.zeros((self.lanes, bucket, D), dtype), 0
+                ),
+                "len": np.full(self.lanes, bucket, np.int32),
+                "out": None,
+            }
 
     def lane_load(self, bucket: int, lane: int, req: Request) -> None:
-        st = self._bstate[bucket]
-        toks = np.zeros(bucket, np.int32)
-        toks[: len(req.tokens)] = req.tokens     # pad up to the bucket shape
-        st["h"] = self._insert(
-            st["h"], jnp.int32(lane), self._embed(self.params, jnp.asarray(toks)[None])
-        )
-        st["len"][lane] = len(req.tokens)
-        if self.residency is not None:
-            # task residency: refilling a lane touches this task's weights —
-            # a miss swaps them in from eNVM and the stall burns wall time on
-            # the shared clock BEFORE the lane's budget is computed (the
-            # stall spends the request's submission-anchored SLO budget)
-            stall = self.residency.acquire(self.task)
-            if stall > 0.0 and self.arbiters:
-                arb = self._arb_of(lane)
-                arb.advance_to(arb.now_s + stall)
-                self.sched.sync_clock()
-        if self.arbiters:
-            self._arb_of(lane).admit(
-                self._arb_key(bucket, lane),
-                deadline_s=self._explicit_budget_remaining(req),
-                cycles_per_layer=self._cycles_for(bucket),
-                energy_scale=self._energy_scale,
+        with TraceAnnotation("engine.lane_load", uid=req.uid, bucket=bucket, lane=lane):
+            st = self._bstate[bucket]
+            toks = np.zeros(bucket, np.int32)
+            toks[: len(req.tokens)] = req.tokens     # pad up to the bucket shape
+            st["h"] = self._insert(
+                st["h"], jnp.int32(lane), self._embed(self.params, jnp.asarray(toks)[None])
             )
+            st["len"][lane] = len(req.tokens)
+            if self.residency is not None:
+                # task residency: refilling a lane touches this task's weights
+                # — a miss swaps them in from eNVM and the stall burns wall
+                # time on the shared clock BEFORE the lane's budget is
+                # computed (the stall spends the request's
+                # submission-anchored SLO budget)
+                stall = self.residency.acquire(self.task)
+                if stall > 0.0 and self.arbiters:
+                    arb = self._arb_of(lane)
+                    arb.advance_to(arb.now_s + stall)
+                    self.sched.sync_clock()
+            if self.arbiters:
+                with TraceAnnotation("dvfs.admit"):
+                    self._arb_of(lane).admit(
+                        self._arb_key(bucket, lane),
+                        deadline_s=self._explicit_budget_remaining(req),
+                        cycles_per_layer=self._cycles_for(bucket),
+                        energy_scale=self._energy_scale,
+                    )
+
+    def _arbitrate(self, bucket: int, active: np.ndarray, st: Dict[str, Any]):
+        """ONE (V, f) PER CLOCK DOMAIN for this fused step: each replica's
+        arbiter arbitrates its own active lane slab independently, then every
+        clock fast-forwards to the fleet max — the SPMD barrier (devices
+        leave the collective step together; waiting burns wall time, not
+        operating-point state).  Telemetry deltas accrue HERE (not in run())
+        so step()-driven serving attributes its arbiter work to this server
+        too; the actual step duration feeds the scheduler clock via
+        step_dt_s.  With one replica this is exactly the single shared-clock
+        arbitration.  Returns the step's decision (a tuple, one per domain
+        that stepped, with several replicas)."""
+        before = [a.telemetry() for a in self.arbiters]
+        decisions = []
+        L = self.lanes_per_replica
+        slabs = [
+            (arb, [
+                self._arb_key(bucket, i)
+                for i in range(r * L, (r + 1) * L) if active[i]
+            ])
+            for r, arb in enumerate(self.arbiters)
+        ]
+        # barrier-aware pacing: the fleet step lasts as long as its slowest
+        # domain, so no domain may pick a point below the fleet's tightest
+        # lane requirement (see BatchedDVFSArbiter.step)
+        floor = max(
+            (arb.required_hz(k) for arb, keys in slabs for k in keys),
+            default=0.0,
+        )
+        for arb, keys in slabs:
+            if keys:
+                decisions.append(arb.step(keys, floor_hz=floor))
+        t = max(a.now_s for a in self.arbiters)
+        for a in self.arbiters:
+            a.advance_to(t)
+        for b4, a in zip(before, self.arbiters):
+            after = a.telemetry()
+            for k in self._arb_acc:
+                self._arb_acc[k] += after[k] - b4[k]
+        # advance the scheduler clock TO the shared arbiter clock rather than
+        # by an independently summed dt: combined with the clock_s() sync at
+        # submit()/step(), every server sharing the arbiter judges EDF slack,
+        # queue waits, and admission quotes on the one hardware timeline
+        # deadlines are judged by
+        st["dt"] = max(t - self.sched.now_s, 0.0)
+        return decisions[0] if len(decisions) == 1 else tuple(decisions)
 
     def lanes_step(self, bucket: int, active: np.ndarray):
-        st = self._bstate[bucket]
-        decision = None
-        if self.arbiters:
-            # ONE (V, f) PER CLOCK DOMAIN for this fused step: each replica's
-            # arbiter arbitrates its own active lane slab independently, then
-            # every clock fast-forwards to the fleet max — the SPMD barrier
-            # (devices leave the collective step together; waiting burns wall
-            # time, not operating-point state).  Telemetry deltas accrue HERE
-            # (not in run()) so step()-driven serving attributes its arbiter
-            # work to this server too; the actual step duration feeds the
-            # scheduler clock via step_dt_s.  With one replica this is
-            # exactly the single shared-clock arbitration.
-            before = [a.telemetry() for a in self.arbiters]
-            decisions = []
-            L = self.lanes_per_replica
-            slabs = [
-                (arb, [
-                    self._arb_key(bucket, i)
-                    for i in range(r * L, (r + 1) * L) if active[i]
-                ])
-                for r, arb in enumerate(self.arbiters)
-            ]
-            # barrier-aware pacing: the fleet step lasts as long as its
-            # slowest domain, so no domain may pick a point below the
-            # fleet's tightest lane requirement (see BatchedDVFSArbiter.step)
-            floor = max(
-                (arb.required_hz(k) for arb, keys in slabs for k in keys),
-                default=0.0,
-            )
-            for arb, keys in slabs:
-                if keys:
-                    decisions.append(arb.step(keys, floor_hz=floor))
-            t = max(a.now_s for a in self.arbiters)
-            for a in self.arbiters:
-                a.advance_to(t)
-            for b4, a in zip(before, self.arbiters):
-                after = a.telemetry()
-                for k in self._arb_acc:
-                    self._arb_acc[k] += after[k] - b4[k]
-            decision = decisions[0] if len(decisions) == 1 else tuple(decisions)
-            # advance the scheduler clock TO the shared arbiter clock rather
-            # than by an independently summed dt: combined with the
-            # clock_s() sync at submit()/step(), every server sharing the
-            # arbiter judges EDF slack, queue waits, and admission quotes on
-            # the one hardware timeline deadlines are judged by
-            st["dt"] = max(t - self.sched.now_s, 0.0)
-        h, lg, ent, retire = self._step(
-            self.params, st["h"], jnp.asarray(active), jnp.asarray(st["len"]),
-            jnp.float32(self.threshold),
-        )
-        st["h"] = h
-        st["out"] = (np.asarray(lg), np.asarray(ent), np.asarray(retire), decision)
-        return st["out"]
+        with TraceAnnotation("engine.lanes_step", bucket=bucket,
+                             n_active=int(active.sum())):
+            st = self._bstate[bucket]
+            decision = None
+            if self.arbiters:
+                with TraceAnnotation("dvfs.step"):
+                    decision = self._arbitrate(bucket, active, st)
+            with TraceAnnotation("engine.dispatch"):
+                h, lg, ent, retire = self._step(
+                    self.params, st["h"], jnp.asarray(active), jnp.asarray(st["len"]),
+                    jnp.float32(self.threshold),
+                )
+            st["h"] = h
+            # the host waits here for the step's outputs (device time + D2H)
+            with TraceAnnotation("engine.fetch"):
+                st["out"] = (np.asarray(lg), np.asarray(ent), np.asarray(retire), decision)
+            return st["out"]
 
     def lane_advance(
         self, bucket: int, lane: int, req: Request, out, depth: int
@@ -597,9 +612,9 @@ class ClassifierServer:
         lg, _, _, _ = self._bstate[bucket]["out"]
         req.result = lg[lane]
         req.exit_layer = depth
-        req.finish_time = time.time()
         if self.arbiters:
-            rep = self._arb_of(lane).retire(self._arb_key(bucket, lane), depth)
+            with TraceAnnotation("dvfs.retire"):
+                rep = self._arb_of(lane).retire(self._arb_key(bucket, lane), depth)
             req.energy_j = rep.energy_j
             req.latency_s = rep.latency_s
             req.op_vdd = rep.slowest_op.vdd
@@ -639,7 +654,8 @@ class ClassifierServer:
         _fold_miss(acc, req, req.latency_s or 0.0, ctrl.target_latency_s)
 
     def bucket_end(self, bucket: int) -> None:
-        del self._bstate[bucket]
+        with TraceAnnotation("engine.bucket_end", bucket=bucket):
+            del self._bstate[bucket]
 
     def lane_checkpoint(self, bucket: int, lane: int, req: Request):
         """Snapshot ``(h, kv_len)`` at the layer boundary (the scheduler
@@ -1350,7 +1366,6 @@ class DecoderServer:
                 )
             else:
                 req.result = np.asarray(logits[lane])
-        req.finish_time = time.time()
         st["reqs"][lane] = None
         acc = self._acc
         acc["retired"] += 1

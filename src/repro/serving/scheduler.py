@@ -78,6 +78,7 @@ admit -> [preempt/checkpoint] -> retire lifecycle.
 """
 from __future__ import annotations
 
+import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import (
@@ -92,6 +93,7 @@ from typing import (
 )
 
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 if TYPE_CHECKING:  # circular: engine imports scheduler
     from repro.serving.engine import Request
@@ -462,11 +464,12 @@ class LaneScheduler:
         in-flight drain; it lands in a later refill of its bucket.  Returns
         the bucket it landed in.
 
-        Stamps MODELED clocks only (``arrival_s`` / ``arrival_step``).  The
-        wall-clock ``req.submit_time`` is deliberately NOT written here:
-        deadline math runs entirely on the modeled clock, and a wall-clock
-        stamp on the same object invited silently mixing the two (callers
-        that want wall time set it themselves)."""
+        Deadline math runs on the MODELED clocks stamped here
+        (``arrival_s`` / ``arrival_step``).  The wall stamp ``queued_at``
+        (``time.perf_counter``, like ``admitted_at`` and ``retired_at``) is
+        written for observability only: no scheduling, DVFS or admission
+        code reads it."""
+        req.queued_at = time.perf_counter()
         self.sync_clock()
         req.arrival_step = self._dense_steps
         req.arrival_s = self.now_s
@@ -742,36 +745,68 @@ class LaneScheduler:
     # ----------------------------------------------------------- stepping
     def step(self) -> Optional[StepReport]:
         """Advance ONE bucket by one fused step; returns what happened, or
-        ``None`` when no work remains anywhere."""
-        self.sync_clock()       # another server may have advanced the shared
-                                # timeline: EDF slack and admit_s need it
-        views = self._candidates()
-        if not views:
-            return None
-        bucket = self.policy.choose(views, self.now_s)
-        assert any(v.bucket == bucket for v in views), (
-            f"policy chose bucket {bucket} which has no queued or active work"
-        )
-        eng = self.engine
-        run = self._open.get(bucket)
-        if run is None:
-            eng.bucket_begin(bucket)
-            run = _BucketRun(
-                lane_req=[None] * self.lanes,
-                lane_depth=np.zeros(self.lanes, np.int32),
-                active=np.zeros(self.lanes, bool),
-            )
-            self._open[bucket] = run
+        ``None`` when no work remains anywhere.
 
-        # evict budget-free lanes for queued explicit SLOs BEFORE refill, so
-        # the freed lanes take the contracts in this very step
+        Each phase is a profiler span (inert unless a trace is active), so a
+        trace shows where the host's time goes: ``sched.step`` holds
+        ``sched.choose``, ``sched.refill``, the engine's own step spans and
+        ``sched.retire``."""
+        with StepTraceAnnotation("sched.step", step_num=self._dense_steps):
+            with TraceAnnotation("sched.choose"):
+                self.sync_clock()   # another server may have advanced the shared
+                                    # timeline: EDF slack and admit_s need it
+                views = self._candidates()
+                if not views:
+                    return None
+                bucket = self.policy.choose(views, self.now_s)
+            assert any(v.bucket == bucket for v in views), (
+                f"policy chose bucket {bucket} which has no queued or active work"
+            )
+            eng = self.engine
+            run = self._open.get(bucket)
+            if run is None:
+                eng.bucket_begin(bucket)
+                run = _BucketRun(
+                    lane_req=[None] * self.lanes,
+                    lane_depth=np.zeros(self.lanes, np.int32),
+                    active=np.zeros(self.lanes, bool),
+                )
+                self._open[bucket] = run
+            step_idx = self._dense_steps
+            with TraceAnnotation("sched.refill", bucket=bucket):
+                self._refill(bucket, run, step_idx)
+
+            out = eng.lanes_step(bucket, run.active.copy())
+            n_active = int(run.active.sum())
+            self._dense_steps += 1
+            self._lane_steps += n_active
+            self._bucket_steps[bucket] = self._bucket_steps.get(bucket, 0) + 1
+            # the engine may report the step's ACTUAL modeled duration (DVFS op
+            # period + switching stalls); fall back to the nominal estimate so
+            # the EDF clock cannot drift from the clock deadlines are judged by
+            dt_hook = getattr(eng, "step_dt_s", None)
+            dt = dt_hook(bucket) if dt_hook is not None else None
+            self.now_s += float(dt) if dt is not None else float(self.step_time_fn(bucket))
+            run.lane_depth[run.active] += 1
+
+            report = StepReport(bucket=bucket, n_active=n_active)
+            with TraceAnnotation("sched.retire"):
+                self._retire(bucket, run, out, step_idx, report)
+
+            if not run.active.any() and not self.queues.get(bucket):
+                eng.bucket_end(bucket)
+                del self._open[bucket]
+            return report
+
+    def _refill(self, bucket: int, run: _BucketRun, step_idx: int) -> None:
+        """Fill every free lane of the chosen bucket from its queue
+        (continuation batching: retired lanes never idle while work is
+        queued), first evicting budget-free lanes for queued explicit SLOs so
+        the freed lanes take the contracts in this very step."""
         if self.preempt:
             self._maybe_preempt(bucket, run)
-
-        # refill every free lane from this bucket's queue (continuation
-        # batching: retired lanes never idle while work is queued)
+        eng = self.engine
         q = self.queues.get(bucket)
-        step_idx = self._dense_steps
         # replica-aware refill: a lane only takes work compatible with its
         # clock domain (engines without replicas report domain 0 for every
         # lane, and unpinned requests run anywhere — the common path is
@@ -784,6 +819,8 @@ class LaneScheduler:
                 )
                 if req is None:
                     continue    # everything queued is pinned elsewhere
+                if req.admitted_at is None:
+                    req.admitted_at = time.perf_counter()
                 if req.checkpoint is not None:
                     # preempted earlier: restore the checkpointed state and
                     # resume at its saved depth — completed layers are NOT
@@ -804,20 +841,11 @@ class LaneScheduler:
                 self._refills += 1
         assert run.active.any(), "candidate bucket must have work after refill"
 
-        out = eng.lanes_step(bucket, run.active.copy())
-        n_active = int(run.active.sum())
-        self._dense_steps += 1
-        self._lane_steps += n_active
-        self._bucket_steps[bucket] = self._bucket_steps.get(bucket, 0) + 1
-        # the engine may report the step's ACTUAL modeled duration (DVFS op
-        # period + switching stalls); fall back to the nominal estimate so
-        # the EDF clock cannot drift from the clock deadlines are judged by
-        dt_hook = getattr(eng, "step_dt_s", None)
-        dt = dt_hook(bucket) if dt_hook is not None else None
-        self.now_s += float(dt) if dt is not None else float(self.step_time_fn(bucket))
-        run.lane_depth[run.active] += 1
-
-        report = StepReport(bucket=bucket, n_active=n_active)
+    def _retire(self, bucket: int, run: _BucketRun, out: Any, step_idx: int,
+                report: StepReport) -> None:
+        """Per-lane host postprocess of the step just run; retires the lanes
+        the engine says are done."""
+        eng = self.engine
         for i in range(self.lanes):
             if not run.active[i]:
                 continue
@@ -826,6 +854,7 @@ class LaneScheduler:
                 eng.lane_finish(bucket, i, req, int(run.lane_depth[i]))
                 req.retire_step = step_idx
                 req.retire_s = self.now_s
+                req.retired_at = time.perf_counter()
                 self.done[req.uid] = req
                 self._completed.append(req)
                 self._sentences += 1
@@ -844,11 +873,6 @@ class LaneScheduler:
                 report.retired.append(req)
                 run.lane_req[i] = None
                 run.active[i] = False
-
-        if not run.active.any() and not self.queues.get(bucket):
-            eng.bucket_end(bucket)
-            del self._open[bucket]
-        return report
 
     def poll(self, *, pin: bool = False) -> List["Request"]:
         """Requests retired since the last ``poll()`` (completion order).

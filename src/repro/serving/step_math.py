@@ -101,16 +101,17 @@ def classifier_fused_step(
         )
         return h2[0]
 
-    h_new = jax.vmap(one_lane)(h, lengths)
+    h_new = jax.vmap(one_lane)(h, lengths)     # phases "attention", "mlp"
     h = jnp.where(active[:, None, None], h_new, h)
-    lg = offramp_logits(h, model._offramp(params))
-    if use_pallas:
-        from repro.kernels import dispatch
+    with jax.named_scope("offramp"):
+        lg = offramp_logits(h, model._offramp(params))
+        if use_pallas:
+            from repro.kernels import dispatch
 
-        ent = dispatch.entropy(lg)
-    else:
-        ent = entropy_from_logits(lg)
-    retire = jnp.logical_and(active, ent < threshold)
+            ent = dispatch.entropy(lg)
+        else:
+            ent = entropy_from_logits(lg)
+        retire = jnp.logical_and(active, ent < threshold)
     return h, lg, ent, retire
 
 

@@ -4,6 +4,7 @@ oldest-drop), and preemptive lane checkpointing (evict a budget-free lane for
 a tighter-SLO arrival, restore it later with zero re-run layers and zero new
 traces)."""
 import dataclasses
+import time
 
 import jax
 import jax.numpy as jnp
@@ -561,15 +562,45 @@ class TestTelemetryGuards:
 
 
 class TestModeledClockOnly:
-    def test_submit_never_stamps_wall_clock(self):
-        """The scheduler's modeled-time path must not mix in wall-clock reads:
-        submit() stamps arrival_s/arrival_step only, and submit_time stays at
-        its caller-owned default."""
+    def test_modeled_clock_ignores_wall_stamps(self, monkeypatch):
+        """Deadline math must not mix in the wall clock.  submit() writes
+        the wall stamp ``queued_at`` beside the modeled ``arrival_s`` /
+        ``arrival_step``, which stay what the modeled clock says, and a wall
+        clock that jumps about changes no scheduling decision: every step,
+        retirement and modeled stamp matches a run on the real clock."""
+        import types
+
+        from repro.serving import scheduler
+
         model, params, cfg = _albert_model()
         batch = _batch(cfg)
-        srv = ClassifierServer(model, params, batch_lanes=2, buckets=(16,))
-        req = Request(uid=0, tokens=batch["tokens"][0][:12])
-        srv.submit(req)
-        assert req.submit_time == 0.0
-        assert req.arrival_s == srv.sched.now_s
-        assert req.arrival_step == 0
+        wild = iter(np.random.default_rng(0).normal(0.0, 1e9, 10_000))
+
+        def serve(clock):
+            monkeypatch.setattr(scheduler, "time", types.SimpleNamespace(perf_counter=clock))
+            srv = ClassifierServer(model, params, batch_lanes=2, buckets=(16, 32))
+            reqs = [
+                Request(uid=i, tokens=batch["tokens"][i][: 12 if i % 2 else 24],
+                        deadline_s=float(cfg.n_layers * 3) if i == 3 else None)
+                for i in range(5)
+            ]
+            srv.submit(reqs[0])
+            stamped = (reqs[0].queued_at, reqs[0].arrival_s, reqs[0].arrival_step,
+                       srv.sched.now_s)
+            for r in reqs[1:]:
+                srv.submit(r)
+            steps = []
+            while (rep := srv.step()) is not None:
+                steps.append((rep.bucket, rep.n_active, [r.uid for r in rep.retired]))
+            modeled = [(r.arrival_s, r.arrival_step, r.admit_s, r.first_compute_step,
+                        r.retire_s, r.retire_step) for r in reqs]
+            return stamped, steps, modeled, [(r.queued_at, r.admitted_at, r.retired_at)
+                                            for r in reqs]
+
+        real = serve(time.perf_counter)
+        jumpy = serve(lambda: float(next(wild)))
+        queued_at, arrival_s, arrival_step, now_s = jumpy[0]
+        assert queued_at is not None and queued_at != real[0][0]   # the wall stamp is written
+        assert arrival_s == now_s and arrival_step == 0            # the modeled ones unchanged
+        assert jumpy[1] == real[1] and jumpy[2] == real[2]
+        assert all(None not in stamps for stamps in jumpy[3])

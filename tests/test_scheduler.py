@@ -437,6 +437,23 @@ class _NullEngine:
         pass
 
 
+class TestPolicyContract:
+    def test_policy_without_a_choice_fails_loudly(self):
+        """A policy that picks no bucket while work is queued trips the
+        step's assertion; it never passes for "no work left", which would
+        end a ``while step() is not None`` drain with requests stranded."""
+
+        class _NoChoice:
+            def choose(self, views, now_s):
+                return None
+
+        sched = LaneScheduler(2, _NullEngine(), buckets=(8,), policy=_NoChoice())
+        assert sched.step() is None          # nothing queued: no work left
+        sched.submit(Request(uid=0, tokens=np.zeros(4, np.int32)))
+        with pytest.raises(AssertionError, match="policy chose bucket None"):
+            sched.step()
+
+
 class TestRetiredRequestRetention:
     """ROADMAP retention item: a long-running submit/step/poll server must
     not accumulate every retired Request forever — poll() releases payloads

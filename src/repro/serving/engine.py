@@ -195,6 +195,29 @@ def _resolve_mesh(replicas: int, mesh):
     return replicas, mesh
 
 
+def _pack_step_outputs(lg, ent, retire) -> jnp.ndarray:
+    """One f32 ``[lanes, C + 2]`` array — logits, then entropy, then retire as
+    0.0/1.0 — so the host reads a fused step in one transfer.  Every value is
+    exact in f32; the concat runs along the last axis and keeps a lane
+    sharding."""
+    f32 = jnp.float32
+    return jnp.concatenate(
+        [lg.astype(f32), ent.astype(f32)[:, None], retire.astype(f32)[:, None]],
+        axis=-1,
+    )
+
+
+def _unpack_step_outputs(out: np.ndarray, dtypes):
+    """Host views of ``_pack_step_outputs``: ``(logits, entropy, retire)``
+    in the dtypes the step computed them in."""
+    lg_dtype, ent_dtype = dtypes
+    return (
+        out[:, :-2].astype(lg_dtype, copy=False),
+        out[:, -2].astype(ent_dtype, copy=False),
+        out[:, -1] > 0,
+    )
+
+
 # unique per-server prefix for arbiter lane keys: with cross-bucket time
 # slicing several buckets (and, via a shared arbiter, several servers) can
 # hold lanes in flight at once, so the raw lane index no longer identifies a
@@ -333,8 +356,13 @@ class ClassifierServer:
             preempt=preempt,
         )
         # per-bucket engine state: {"h": [lanes, S, D], "len": [lanes],
-        # "out": last step's host copies} — several buckets open at once
+        # "out": last step's (logits, entropy, retire, decision), host views
+        # into the one packed [lanes, C + 2] read} — several buckets open
         self._bstate: Dict[int, Dict[str, Any]] = {}
+        # blocking device-to-host reads made by lanes_step (one per step),
+        # and the dtypes of the logits and entropy that the packing widens
+        self._host_reads = 0
+        self._out_dtypes = None
         # "embed"/"step"/"insert" keyed by S; "step_replica" keyed by
         # (S, replicas) — the per-(bucket, mesh) recompile telemetry the
         # sharded CI gates read (identical to (S, 1) on the unsharded path,
@@ -371,15 +399,18 @@ class ClassifierServer:
                 self._traces["step_replica"].get(rk, 0) + 1
             )
             if self._mesh is None:
-                return step_math.classifier_fused_step(
+                h, lg, ent, retire = step_math.classifier_fused_step(
                     model, params, h, active, lengths, threshold,
                     use_pallas=self.use_pallas, block_masks=self._block_masks,
                 )
-            return step_math.sharded_classifier_fused_step(
-                model, params, h, active, lengths, threshold,
-                mesh=self._mesh, use_pallas=self.use_pallas,
-                block_masks=self._block_masks,
-            )
+            else:
+                h, lg, ent, retire = step_math.sharded_classifier_fused_step(
+                    model, params, h, active, lengths, threshold,
+                    mesh=self._mesh, use_pallas=self.use_pallas,
+                    block_masks=self._block_masks,
+                )
+            self._out_dtypes = (lg.dtype, ent.dtype)
+            return h, _pack_step_outputs(lg, ent, retire)
 
         def insert_fn(h, lane, h_new):
             S = h.shape[1]
@@ -586,14 +617,18 @@ class ClassifierServer:
                 with TraceAnnotation("dvfs.step"):
                     decision = self._arbitrate(bucket, active, st)
             with TraceAnnotation("engine.dispatch"):
-                h, lg, ent, retire = self._step(
+                h, packed = self._step(
                     self.params, st["h"], jnp.asarray(active), jnp.asarray(st["len"]),
                     jnp.float32(self.threshold),
                 )
+                packed.copy_to_host_async()
             st["h"] = h
-            # the host waits here for the step's outputs (device time + D2H)
+            # the host waits here for the step's one packed output: the rest
+            # of the device time, then a single D2H copy of [lanes, C + 2]
             with TraceAnnotation("engine.fetch"):
-                st["out"] = (np.asarray(lg), np.asarray(ent), np.asarray(retire), decision)
+                out = np.asarray(packed)
+            self._host_reads += 1
+            st["out"] = (*_unpack_step_outputs(out, self._out_dtypes), decision)
             return st["out"]
 
     def lane_advance(
@@ -713,6 +748,7 @@ class ClassifierServer:
             "avg_exit_layer": avg_exit,
             "runtime_savings": 1.0 - avg_exit / self.cfg.n_layers,
             "step_traces": sum(self._traces["step"].values()),
+            "host_reads": self._host_reads,
             "embed_traces": sum(self._traces["embed"].values()),
             "insert_traces": sum(self._traces["insert"].values()),
             "step_traces_per_bucket": dict(self._traces["step"]),

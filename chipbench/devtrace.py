@@ -8,7 +8,9 @@ on synthetic traces without a chip.
   busy time is the length of the union of those intervals inside the
   window, and its idle share is 1 minus busy over the window.
 * A step's device time is the duration of its program's event on the
-  device's "XLA Modules" line (the jitted fused step, ``jit(step_fn)``).
+  device's "XLA Modules" line: the jitted fused step, whose name the
+  cell's driver gives as ``System.step_program`` (the classifier's
+  ``jit(step_fn)``).
 * An idle gap is charged to the benchmark's own host span (``bench.*``)
   that overlaps it most, so the breakdown says what the host was doing
   while the device waited.
@@ -100,9 +102,10 @@ def charge_gaps(idle: list, spans: list) -> collections.Counter:
     return out
 
 
-def reduce(trace: dict, step_pattern: str = r"step_fn") -> dict:
-    """Busy and idle time, step device time and the breakdown of the
-    window that the host span ``bench.window`` marks."""
+def reduce(trace: dict, step_pattern: str) -> dict:
+    """Busy and idle time, device time of the programs whose name
+    ``step_pattern`` finds, and the breakdown of the window that the host
+    span ``bench.window`` marks."""
     windows = [(s, e) for n, s, e in trace["host"] if n == WINDOW_SPAN]
     if not windows or not trace["devices"]:
         return {}

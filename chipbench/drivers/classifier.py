@@ -9,8 +9,9 @@ A deployment serves one model: every run serves the same one, so that the
 run's seed, which draws the traffic, does not change the work a request
 brings.  The harness (``chipbench/run.py``) owns the clock, the traffic
 and the trace; this module owns what is particular to a classifier: its
-answers, their comparison with the plain reference, and the work of a
-step.
+answers, their comparison with the plain reference, the work of a step,
+and for the tests its smoke width, its planted faults and the compile of
+its programs (the contract is in ``drivers/__init__.py``).
 """
 from __future__ import annotations
 
@@ -26,6 +27,12 @@ from chipbench import flops, traffic
 
 PROFILE_BLOCK = 8     # rows per reference call: the reference's memory stays
                       # near one served step's, below the window's peak
+
+# a width the CPU runs in seconds: every cut dimension still a multiple of
+# the 128-wide pruning tile
+SMOKE = dict(hidden_size=128, num_hidden_layers=4, num_attention_heads=4,
+             intermediate_size=256, embedding_size=32, vocab_size=512,
+             max_position_embeddings=128, threshold_profile={"requests": 32})
 
 
 def model_config(cfg: dict):
@@ -212,6 +219,8 @@ def compare(answers, ref_logits, ref_ents, thr: float, limits: dict) -> dict:
 class System:
     """The served path of one configuration."""
 
+    step_program = r"step_fn"      # ``jit(step_fn)``, the fused step
+
     def __init__(self, cfg: dict, mix: dict, devices):
         from repro.models.model import build_model
         from repro.serving.engine import ClassifierServer
@@ -255,9 +264,12 @@ class System:
         )
 
     # ------------------------------------------------------------ serving
-    def submit(self, uid: int, toks: np.ndarray) -> None:
+    def submit(self, uid: int, toks: np.ndarray, answer_len=None) -> None:
         from repro.serving.engine import Request
 
+        if answer_len is not None:
+            raise ValueError("a classifier answers with one label: its traffic states "
+                             "no answer_lengths")
         self.srv.submit(Request(uid=uid, tokens=toks))
 
     def step(self):
@@ -278,6 +290,9 @@ class System:
 
     def close(self) -> None:
         self.srv = None
+
+    def notes(self) -> dict:
+        return {"threshold": self.threshold}
 
     # --------------------------------------------------------------- work
     def step_cost(self, bucket: int) -> tuple:
@@ -304,9 +319,124 @@ class System:
                          for (lg, ex), rl in zip(answers, logits)])
         return np.where(np.isfinite(gaps), gaps, np.inf)
 
-    def control_answers(self, prompts, precision: str = "high") -> list:
+    def control_answers(self, prompts, answers, precision: str = "high") -> list:
         """The control: the reference one precision below the
-        configuration's, put in the program's place, with its own exits."""
+        configuration's, put in the program's place, with its own exits
+        (the served ``answers`` are not needed)."""
         logits, ents = self.run_reference(prompts, precision=precision)
         return [(lg[exit_layer(en, self.threshold) - 1], exit_layer(en, self.threshold))
                 for lg, en in zip(logits, ents)]
+
+
+# ---------------------------------------------------------------- faults
+# Each is planted in the program's ``ClassifierServer.lanes_step``, the call
+# that advances every lane by one fused step and hands the scheduler its
+# answers.  The exchange between chips is not among them: the lane-sharded
+# step has no collective, replicas never talk to each other.
+
+
+def unchanged_state(orig, cfg):
+    def lanes_step(self, bucket, active):
+        h = self._bstate[bucket]["h"]
+        out = orig(self, bucket, active)
+        self._bstate[bucket]["h"] = h        # the step's new state is dropped
+        return out
+    return lanes_step
+
+
+def half_the_lanes(orig, cfg):
+    def lanes_step(self, bucket, active):
+        step = self._step
+
+        def half_step(params, h, act, lengths, thr):
+            # the fused step computes the first half of the lanes only
+            return step(params, h, act.at[act.shape[0] // 2:].set(False), lengths, thr)
+
+        self._step = half_step
+        try:
+            return orig(self, bucket, active)
+        finally:
+            self._step = step
+    return lanes_step
+
+
+def _rewrite(orig, change):
+    def lanes_step(self, bucket, active):
+        lg, ent, retire, decision = orig(self, bucket, active)
+        lg = change(np.array(lg))
+        self._bstate[bucket]["out"] = (lg, ent, retire, decision)
+        return self._bstate[bucket]["out"]
+    return lanes_step
+
+
+def altered_answer(orig, cfg):
+    # lane 0's logits moved by ten times the limit, where they are produced
+    def change(lg):
+        lg[0] += 10 * cfg["checks"]["logit_err"]
+        return lg
+    return _rewrite(orig, change)
+
+
+def swapped_answers(orig, cfg):
+    # the scheduler's bookkeeping: lanes 0 and 1 hand back each other's answer
+    return _rewrite(orig, lambda lg: lg[[1, 0] + list(range(2, len(lg)))])
+
+
+def _planted(wrap):
+    def plant(monkeypatch, cfg):
+        from repro.serving.engine import ClassifierServer
+
+        monkeypatch.setattr(ClassifierServer, "lanes_step",
+                            wrap(ClassifierServer.lanes_step, cfg))
+    return plant
+
+
+FAULTS = {f.__name__: _planted(f)
+          for f in (unchanged_state, half_the_lanes, altered_answer, swapped_answers)}
+
+
+# --------------------------------------------------------------- compile
+def compile_programs(cfg: dict, mix: dict, devices, chips: int) -> dict:
+    """The embed, lane-insert and fused-step programs of every bucket the
+    mix reaches, lowered from the server the cell builds, with its
+    full-width weights (the pruned MLP's tile masks come from them, so
+    they are made on the host's CPU), and compiled for the first ``chips``
+    of ``devices``.  Raises where the compiled step's Pallas kernels
+    (``tpu_custom_call``) are not there as ``use_pallas`` states."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+    from repro.models.model import build_model
+    from repro.serving.engine import ClassifierServer
+
+    mc = model_config(cfg)
+    params = make_weights(cfg, cfg["weights_seed"],
+                          SingleDeviceSharding(jax.devices("cpu")[0]))
+    if chips == 1:
+        rep = lanes = SingleDeviceSharding(devices[0])
+        mesh = None
+    else:
+        mesh = Mesh(np.array(devices[:chips]), ("data",))
+        rep, lanes = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
+    arbiter = make_arbiter(mc, max(cfg["buckets"])) if cfg["arbiter"] else None
+    srv = ClassifierServer(build_model(mc), params, batch_lanes=cfg["lanes"],
+                           buckets=cfg["buckets"], use_pallas=cfg["use_pallas"],
+                           arbiter=arbiter, mesh=mesh)
+    spec = lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s)  # noqa: E731
+    p_spec = jax.tree_util.tree_map(lambda a: spec(a, rep), params)
+    D, n = cfg["hidden_size"], srv.lanes
+    out = {}
+    for b in traffic.buckets_reached(mix, cfg["buckets"]):
+        h = jax.ShapeDtypeStruct((n, b, D), jnp.float32, sharding=lanes)
+        row = jax.ShapeDtypeStruct((1, b, D), jnp.float32, sharding=rep)
+        out[f"embed.{b}"] = srv._embed.lower(
+            p_spec, jax.ShapeDtypeStruct((1, b), jnp.int32, sharding=rep)).compile()
+        out[f"insert.{b}"] = srv._insert.lower(
+            h, jax.ShapeDtypeStruct((), jnp.int32, sharding=rep), row).compile()
+        out[f"step.{b}"] = step = srv._step.lower(
+            p_spec, h, jax.ShapeDtypeStruct((n,), jnp.bool_, sharding=lanes),
+            jax.ShapeDtypeStruct((n,), jnp.int32, sharding=lanes),
+            jax.ShapeDtypeStruct((), jnp.float32, sharding=rep)).compile()
+        if ("tpu_custom_call" in step.as_text()) != cfg["use_pallas"]:
+            raise AssertionError(f"bucket {b}: the compiled step's Pallas kernels do not "
+                                 f"match use_pallas={cfg['use_pallas']}")
+    return out

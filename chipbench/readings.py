@@ -35,12 +35,13 @@ def main(argv=None) -> int:
         res = run.measure(cell, cfg, mix, seed, args.seconds, False, devices,
                           t_start=time.perf_counter())
         sysm = res["system"]
-        sides = {"program": res["answers"], "control": sysm.control_answers(res["sample"]),
-                 "bf16": sysm.control_answers(res["sample"], "bf16")}
-        control = sysm.check(res["sample"], sides["control"])
+        sample, served = res["sample"], res["answers"]
+        sides = {"program": served, "control": sysm.control_answers(sample, served),
+                 "bf16": sysm.control_answers(sample, served, "bf16")}
+        control = sysm.check(sample, sides["control"])
         stats = {}
         for side, answers in sides.items():
-            e = sysm.answer_errors(res["sample"], answers)
+            e = sysm.answer_errors(sample, answers)
             stats[side] = {"max": e.max(), "p99": np.percentile(e, 99),
                            "p95": np.percentile(e, 95), "p90": np.percentile(e, 90),
                            "p50": np.median(e), "mean": e.mean(),
@@ -48,11 +49,11 @@ def main(argv=None) -> int:
         print(json.dumps({"stats": {k: {m: float(x) for m, x in v.items()}
                                     for k, v in stats.items()}}), flush=True)
         print(json.dumps({
-            "seed": seed, "threshold": res["system"].threshold,
-            "answers": len(res["sample"]), "failed": res["failed"],
+            "seed": seed, **sysm.notes(),
+            "answers": len(sample), "failed": res["failed"],
             "program": {k: v for k, (v, _) in res["checks"].items()},
             "control": {k: v for k, (v, _) in control.items()},
-            "bf16": {k: v for k, (v, _) in sysm.check(res["sample"], sides["bf16"]).items()},
+            "bf16": {k: v for k, (v, _) in sysm.check(sample, sides["bf16"]).items()},
         }), flush=True)
     return 0
 

@@ -72,6 +72,12 @@ def load_cell(name: str):
     return bench, cell, cfg, traffic.load(cell["traffic"])
 
 
+def load_driver(cfg: dict):
+    """The module that serves configuration ``cfg``:
+    ``drivers/<cfg["driver"]>.py``, to the contract in ``drivers/__init__.py``."""
+    return importlib.import_module(f"chipbench.drivers.{cfg['driver']}")
+
+
 def find_devices(chips: int):
     """The first ``chips`` TPU devices; refuses anything else."""
     import jax
@@ -139,6 +145,12 @@ class CompileWatch:
         jax.monitoring.unregister_event_duration_listener(self._listen)
 
 
+def asked(answer_lens, i: int) -> dict:
+    """``submit``'s ``answer_len`` for request ``i``, where the mix states
+    answer lengths; nothing where it does not."""
+    return {} if answer_lens is None else {"answer_len": int(answer_lens[i])}
+
+
 class Load:
     """The requests of one run and what became of them."""
 
@@ -152,6 +164,10 @@ class Load:
             self.depth = mix["queue_per_lane"] * sysm.lanes
             n = mix["pool"]
         self.pool = traffic.tokens(traffic.lengths(mix, n, g), g, vocab)
+        # the tokens each pool entry asks for, where the mix states them
+        self.answer_lens = (traffic.lengths(mix, n, traffic.rng(seed, traffic.ANSWER_WINDOW),
+                                            key="answer_lengths")
+                            if "answer_lengths" in mix else None)
         self.sent = []                   # uid -> pool index
         self.sent_at, self.done_at, self.answers = [], {}, {}
         self.longest_s = 0.0             # the longest pass of the serving loop
@@ -160,10 +176,14 @@ class Load:
         uid = len(self.sent)
         self.sent.append(uid % len(self.pool))
         self.sent_at.append(now)
-        sysm.submit(uid, self.pool[self.sent[uid]])
+        sysm.submit(uid, self.pool[self.sent[uid]], **asked(self.answer_lens, self.sent[uid]))
 
     def tokens(self, uid: int):
         return self.pool[self.sent[uid]]
+
+    def size(self, uid: int) -> int:
+        """The request's prompt tokens and the answer tokens it asks for."""
+        return len(self.tokens(uid)) + asked(self.answer_lens, self.sent[uid]).get("answer_len", 0)
 
 
 def serve(sysm, load: Load, t0: float, until: float, span, *, closing: bool) -> list:
@@ -208,14 +228,19 @@ def serve(sysm, load: Load, t0: float, until: float, span, *, closing: bool) -> 
 
 def warm(sysm, mix: dict, seed: int, vocab: int) -> None:
     """Compile and run every shape the mix reaches: two lanes' worth of
-    requests in each of its buckets, drained."""
+    requests in each of its buckets, drained.  Where the mix states answer
+    lengths, each bucket's requests ask for a stratified set of them."""
     g = traffic.rng(seed, traffic.WARM)
+    g_answer = traffic.rng(seed, traffic.ANSWER_WARM)
     uid, below = -1, 0
     for b in sorted(sysm.cfg["buckets"]):
         if b in sysm.buckets:
-            for toks in traffic.tokens(traffic.lengths(mix, 2 * sysm.lanes, g, below + 1, b),
-                                       g, vocab):
-                sysm.submit(uid, toks)
+            prompts = traffic.tokens(traffic.lengths(mix, 2 * sysm.lanes, g, below + 1, b),
+                                     g, vocab)
+            lens = (traffic.lengths(mix, len(prompts), g_answer, key="answer_lengths")
+                    if "answer_lengths" in mix else None)
+            for i, toks in enumerate(prompts):
+                sysm.submit(uid, toks, **asked(lens, i))
                 uid -= 1
         below = b
     while sysm.step() is not None:
@@ -232,11 +257,12 @@ def memory_peak(devices) -> int:
 
 
 def sample_uids(load: Load, seed: int) -> list:
-    """A seeded sample of the answered requests, the longest among them."""
+    """A seeded sample of the answered requests, the longest among them
+    (prompt and asked answer)."""
     uids = sorted(load.answers)
     if len(uids) <= SAMPLE:
         return uids
-    longest = max(uids, key=lambda u: len(load.tokens(u)))
+    longest = max(uids, key=load.size)
     rest = [u for u in uids if u != longest]
     pick = traffic.rng(seed, traffic.SAMPLE).choice(len(rest), SAMPLE - 1, replace=False)
     return sorted([longest] + [rest[i] for i in pick])
@@ -249,17 +275,16 @@ def measure(cell: dict, cfg: dict, mix: dict, seed: int, seconds: float, trace: 
 
     setup_compile_cache()
     peaks = peaks_for(devices[0].device_kind)
-    driver = importlib.import_module(f"chipbench.drivers.{cfg['driver']}")
     t_build = time.perf_counter()
-    sysm = driver.System(cfg, mix, devices)
+    sysm = load_driver(cfg).System(cfg, mix, devices)
     t_warm = time.perf_counter()
     built_peak = memory_peak(devices)
     vocab = cfg["vocab_size"]
     warm(sysm, mix, seed, vocab)
-    print(f"setup: {t_build - t_start:.2f}s to the build, {t_warm - t_build:.2f}s to build "
-          f"(weights, threshold, server), of which {sysm.reference_s:.2f}s the reference's "
-          f"threshold profiling (not set-up), {time.perf_counter() - t_warm:.2f}s to warm "
-          f"buckets {sysm.buckets}", file=sys.stderr)
+    print(f"setup: {t_build - t_start:.2f}s to the build, {t_warm - t_build:.2f}s to build, "
+          f"of which {sysm.reference_s:.2f}s in the reference (not set-up), "
+          f"{time.perf_counter() - t_warm:.2f}s to warm buckets {sysm.buckets}",
+          file=sys.stderr)
     load = Load(sysm, mix, seconds, seed, vocab)
     if trace:
         log_dir = tempfile.mkdtemp(prefix="chipbench-trace-")
@@ -304,7 +329,7 @@ def measure(cell: dict, cfg: dict, mix: dict, seed: int, seconds: float, trace: 
     }
     if trace:
         try:
-            reduced = devtrace.reduce(devtrace.load(log_dir))
+            reduced = devtrace.reduce(devtrace.load(log_dir), sysm.step_program)
         finally:
             shutil.rmtree(log_dir, ignore_errors=True)
         if reduced:
@@ -325,7 +350,8 @@ def measure(cell: dict, cfg: dict, mix: dict, seed: int, seconds: float, trace: 
     print(f"window {window_s:.3f}s: {attempted} attempted, {len(load.answers)} answered, "
           f"{len(in_window)} in the window, {compiles.count} compiles in the window, "
           f"{len(stepped)} steps, longest loop pass {longest_s * 1e3:.1f} ms, "
-          f"threshold {sysm.threshold}; device memory peak {peak} bytes, "
+          f"{' '.join(f'{k} {v}' for k, v in sysm.notes().items())}; "
+          f"device memory peak {peak} bytes, "
           f"{built_peak} before warm-up", file=sys.stderr)
     if load.open and load.sent:
         lag = np.array(load.sent_at) - load.due[:len(load.sent_at)]

@@ -14,7 +14,6 @@ from __future__ import annotations
 import argparse
 import contextlib
 import gc
-import importlib
 import json
 import sys
 import time
@@ -39,8 +38,7 @@ def main(argv=None) -> int:
         raise SystemExit("a sweep needs an open-loop (poisson) mix")
     devices = run.find_devices(cell["chips"])
     run.setup_compile_cache()
-    driver = importlib.import_module(f"chipbench.drivers.{cfg['driver']}")
-    sysm = driver.System(cfg, mix, devices)
+    sysm = run.load_driver(cfg).System(cfg, mix, devices)
     run.warm(sysm, mix, args.seed, cfg["vocab_size"])
     gc.collect()
     gc.freeze()

@@ -2,11 +2,18 @@
 import json
 import sys
 
-# a width the CPU runs in seconds: every cut dimension still a multiple of
-# the 128-wide pruning tile
-SMOKE = dict(hidden_size=128, num_hidden_layers=4, num_attention_heads=4,
-             intermediate_size=256, embedding_size=32, vocab_size=512,
-             max_position_embeddings=128)
+
+def shrink(cfg: dict) -> dict:
+    """``cfg`` at the smoke width of its driver (the driver's ``SMOKE``); a
+    dict there updates the group of that name where ``cfg`` has one."""
+    from chipbench import run
+
+    for k, v in run.load_driver(cfg).SMOKE.items():
+        if not isinstance(v, dict):
+            cfg[k] = v
+        elif cfg.get(k):
+            cfg[k] = {**cfg[k], **v}
+    return cfg
 
 
 def patch(setattr_, run, cache_dir) -> None:
@@ -19,10 +26,7 @@ def patch(setattr_, run, cache_dir) -> None:
 
     def small_cell(name):
         bench, cell, cfg, mix = load_cell(name)
-        cfg.update(SMOKE)
-        if cfg.get("threshold_profile"):
-            cfg["threshold_profile"] = dict(cfg["threshold_profile"], requests=32)
-        return bench, cell, cfg, mix
+        return bench, cell, shrink(cfg), mix
 
     setattr_(run, "load_cell", small_cell)
     setattr_(run, "find_devices", lambda chips: jax.devices()[:chips])

@@ -35,7 +35,7 @@ def _trace():
 
 
 def test_reduce_averages_over_chips():
-    r = devtrace.reduce(_trace())
+    r = devtrace.reduce(_trace(), "step_fn")
     assert r["window_s"] == 10.0 and r["devices"] == 2
     assert r["steps"] == 2                                    # the third is outside
     assert r["step_device_s"] == pytest.approx((2 * 1.0 + 2 * 2.0) / 2)
@@ -48,4 +48,16 @@ def test_reduce_averages_over_chips():
 def test_reduce_needs_the_window_span():
     t = _trace()
     t["host"] = t["host"][1:]
-    assert devtrace.reduce(t) == {}
+    assert devtrace.reduce(t, "step_fn") == {}
+
+
+def test_reduce_counts_the_named_program():
+    """The step is the program the driver names: a decoder's decode step,
+    not its prefill, and none where no program has the name."""
+    t = _trace()
+    for lines in t["devices"].values():
+        lines["modules"] = [("jit_decode_step_fn" if n == "jit_step_fn" else "jit_prefill_fn",
+                             s, e) for n, s, e in lines["modules"]]
+    assert devtrace.reduce(t, r"decode_step_fn")["steps"] == 2
+    assert devtrace.reduce(t, r"prefill_fn")["steps"] == 1
+    assert devtrace.reduce(t, r"\bstep_fn")["steps"] == 0

@@ -7,8 +7,6 @@ import subprocess
 import sys
 
 import jax
-import jax.numpy as jnp
-import numpy as np
 import pytest
 
 from chipbench import run
@@ -81,9 +79,7 @@ from pathlib import Path
 import jax
 from chipbench import run, traffic
 from chipbench.tests import smoke
-cfg = json.loads((run.HERE / "configs" / "albert_edgebert.json").read_text())
-cfg.update(smoke.SMOKE)
-cfg["threshold_profile"] = dict(cfg["threshold_profile"], requests=32)
+cfg = smoke.shrink(json.loads((run.HERE / "configs" / "albert_edgebert.json").read_text()))
 smoke.patch(setattr, run, Path(sys.argv[1]))
 res = run.measure({"name": "replicas", "chips": 4}, cfg, traffic.load("mixed_backlog"),
                   2147483659, 1.0, False, jax.devices()[:4])
@@ -130,48 +126,40 @@ def topo():
     jax.config.update("jax_enable_compilation_cache", enabled)
 
 
-@pytest.mark.parametrize("cell", CELLS, ids=[c["name"] for c in CELLS])
-def test_cell_programs_compile_for_v5e(topo, monkeypatch, cell):
-    """The embed, lane-insert and fused-step programs of every bucket the
-    cell's mix reaches, lowered from the server the cell builds, with its
-    full-width weights (the pruned MLP's tile masks come from them)."""
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
-
+def _compile_cell(topo, monkeypatch, name):
+    """The cell's warmed programs, compiled by its driver's
+    ``compile_programs`` at full width for the described chips, with the
+    program's kernels compiled for the chip, not interpreted."""
     from repro.kernels import dispatch
-    from repro.models.model import build_model
-    from repro.serving.engine import ClassifierServer
-
-    from chipbench import traffic
-    from chipbench.drivers import classifier
 
     monkeypatch.setattr(dispatch, "interpret_mode", lambda: False)
-    _, _, cfg, mix = run.load_cell(cell["name"])
-    chips = cell["chips"]
-    mc = classifier.model_config(cfg)
-    params = classifier.make_weights(cfg, 1, SingleDeviceSharding(jax.devices("cpu")[0]))
-    if chips == 1:
-        rep = lanes = SingleDeviceSharding(topo.devices[0])
-        mesh = None
-    else:
-        mesh = Mesh(np.array(topo.devices[:chips]), ("data",))
-        rep, lanes = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
-    srv = ClassifierServer(build_model(mc), params, batch_lanes=cfg["lanes"],
-                           buckets=cfg["buckets"], use_pallas=cfg["use_pallas"],
-                           arbiter=classifier.make_arbiter(mc, 128) if cfg["arbiter"] else None,
-                           mesh=mesh)
-    spec = lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s)  # noqa: E731
-    p_spec = jax.tree_util.tree_map(lambda a: spec(a, rep), params)
-    D, n = cfg["hidden_size"], srv.lanes
-    for b in traffic.buckets_reached(mix, cfg["buckets"]):
-        h = jax.ShapeDtypeStruct((n, b, D), jnp.float32, sharding=lanes)
-        row = jax.ShapeDtypeStruct((1, b, D), jnp.float32, sharding=rep)
-        srv._embed.lower(p_spec, jax.ShapeDtypeStruct((1, b), jnp.int32, sharding=rep)).compile()
-        srv._insert.lower(h, jax.ShapeDtypeStruct((), jnp.int32, sharding=rep), row).compile()
-        step = srv._step.lower(
-            p_spec, h, jax.ShapeDtypeStruct((n,), jnp.bool_, sharding=lanes),
-            jax.ShapeDtypeStruct((n,), jnp.int32, sharding=lanes),
-            jax.ShapeDtypeStruct((), jnp.float32, sharding=rep)).compile()
-        assert ("tpu_custom_call" in step.as_text()) == cfg["use_pallas"]
+    _, cell, cfg, mix = run.load_cell(name)
+    return run.load_driver(cfg).compile_programs(cfg, mix, topo.devices, cell["chips"])
+
+
+@pytest.mark.parametrize("cell", CELLS, ids=[c["name"] for c in CELLS])
+def test_cell_programs_compile_for_v5e(topo, monkeypatch, cell):
+    """Every cell's warmed programs, lowered from the server the cell
+    builds and compiled at full width: its driver says which and checks
+    what the configuration states of them."""
+    assert _compile_cell(topo, monkeypatch, cell["name"])
+
+
+def test_stub_programs_compile_for_v5e(topo, monkeypatch, stub_cell):
+    """The compile check takes a second driver's cell as it takes the
+    classifier's: it calls that driver's ``compile_programs``."""
+    from chipbench.tests import stub_driver
+
+    called, orig = [], stub_driver.compile_programs
+
+    def compile_programs(*args):
+        called.append(args[3])
+        return orig(*args)
+
+    monkeypatch.setattr(stub_driver, "compile_programs", compile_programs)
+    got = _compile_cell(topo, monkeypatch, stub_cell)
+    assert called == [1] and set(got) == {"prefill", "decode"}
+    assert "decode_step_fn" in got["decode"].as_text()
 
 
 def test_refuses_without_the_program(tmp_path):

@@ -92,7 +92,7 @@ def test_program_spans_leave_the_reduction_as_it_was():
     gaps included."""
     plain = test_devtrace._trace()
     both = dict(plain, program=_step(0.0) + _step(5.0))
-    assert devtrace.reduce(both) == devtrace.reduce(plain)
+    assert devtrace.reduce(both, "step_fn") == devtrace.reduce(plain, "step_fn")
 
 
 def test_load_keeps_each_kind_apart(tmp_path):

@@ -1,5 +1,6 @@
 """Every seed offers the same work in another order."""
 import numpy as np
+import pytest
 
 from chipbench import traffic
 
@@ -39,3 +40,15 @@ def test_bucket_slice():
     mix = traffic.load("mixed_open")
     n = traffic.lengths(mix, 64, traffic.rng(0, traffic.WARM), 33, 64)
     assert n.min() >= 33 and n.max() <= 64
+
+
+def test_answer_lengths_are_checked():
+    mix = dict(traffic.load("mixed_open"),
+               answer_lengths={"dist": "lognormal", "median": 6, "sigma": 0.5, "min": 2, "max": 9})
+    assert traffic.check(mix, "t") is mix
+    n = traffic.lengths(mix, 100, traffic.rng(0, traffic.ANSWER_WINDOW), key="answer_lengths")
+    assert n.min() == 2 and n.max() == 9
+    for bad in ({"dist": "zipf", "min": 1, "max": 2}, {"dist": "uniform", "min": 3, "max": 2},
+                {"dist": "uniform", "min": 0, "max": 2}):
+        with pytest.raises(ValueError):
+            traffic.check(dict(mix, answer_lengths=bad), "t")

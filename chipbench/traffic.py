@@ -8,6 +8,9 @@ A traffic file (``traffic/<name>.json``) holds parameters only:
   the server never waits for work.
 * ``lengths``: ``{"dist": "lognormal", "median", "sigma", "min", "max"}``
   or ``{"dist": "uniform", "min", "max"}``, in tokens.
+* ``answer_lengths`` (optional, for paths that serve tokens): the tokens
+  each request asks for, in the grammar of ``lengths``.  Only such a mix
+  passes ``answer_len`` to the driver's ``submit``.
 
 Every seed gets the same set of lengths and the same set of gaps between
 arrivals, in another order: ``n`` draws are taken at the quantiles
@@ -15,7 +18,9 @@ arrivals, in another order: ``n`` draws are taken at the quantiles
 seeds offer the same work and differ in its order and in the token ids,
 which are uniform over the vocabulary.  The gaps of a Poisson process are
 exponential with mean ``1 / rate``, as in the program's
-``serving/workload.PoissonArrivals``.
+``serving/workload.PoissonArrivals``.  Answer lengths are drawn the same
+way on seed streams of their own, so that a mix without them draws every
+other number as it did before they existed.
 """
 from __future__ import annotations
 
@@ -31,6 +36,7 @@ HERE = Path(__file__).resolve().parent
 # seed streams: one per use, so that each is the same whatever the others draw
 WINDOW, WARM, PROFILE, SAMPLE = 1, 2, 3, 4
 FIRST_TOKEN = 5      # ids below this are left for special tokens
+ANSWER_WINDOW, ANSWER_WARM = 6, 7
 
 
 def rng(seed: int, stream: int) -> np.random.Generator:
@@ -38,11 +44,18 @@ def rng(seed: int, stream: int) -> np.random.Generator:
 
 
 def load(name: str) -> dict:
-    mix = json.loads((HERE / "traffic" / f"{name}.json").read_text())
+    return check(json.loads((HERE / "traffic" / f"{name}.json").read_text()), name)
+
+
+def check(mix: dict, name: str) -> dict:
+    """``mix``, where this generator can draw it; raises otherwise."""
     if mix["arrival"] not in ("poisson", "backlog"):
         raise ValueError(f"traffic {name}: unknown arrival {mix['arrival']!r}")
-    if mix["lengths"]["dist"] not in ("lognormal", "uniform"):
-        raise ValueError(f"traffic {name}: unknown length distribution")
+    for key in ["lengths"] + (["answer_lengths"] if "answer_lengths" in mix else []):
+        if mix[key]["dist"] not in ("lognormal", "uniform"):
+            raise ValueError(f"traffic {name}: unknown {key} distribution")
+        if not 1 <= mix[key]["min"] <= mix[key]["max"]:
+            raise ValueError(f"traffic {name}: {key} needs 1 <= min <= max")
     return mix
 
 
@@ -56,10 +69,12 @@ def _quantile(spec: dict, u: np.ndarray) -> np.ndarray:
     return np.clip(np.rint(x), lo, hi).astype(np.int64)
 
 
-def lengths(mix: dict, n: int, g: np.random.Generator, lo: int = 1, hi: int = 1 << 30):
-    """``n`` lengths at the stratified quantiles, shuffled; ``lo``/``hi``
-    keep only the part of the distribution inside one bucket."""
-    spec = dict(mix["lengths"])
+def lengths(mix: dict, n: int, g: np.random.Generator, lo: int = 1, hi: int = 1 << 30,
+            key: str = "lengths"):
+    """``n`` lengths of ``mix[key]`` at the stratified quantiles, shuffled;
+    ``lo``/``hi`` keep only the part of the distribution inside one
+    bucket."""
+    spec = dict(mix[key])
     spec["min"], spec["max"] = max(spec["min"], lo), min(spec["max"], hi)
     if spec["min"] > spec["max"]:
         return np.zeros(0, np.int64)
